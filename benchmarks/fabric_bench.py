@@ -53,10 +53,7 @@ Writes ``BENCH_fabric.json`` at the repo root.
 from __future__ import annotations
 
 import json
-import os
 import pathlib
-import subprocess
-import sys
 import time
 from typing import Dict, List
 
@@ -458,23 +455,12 @@ def run(quick: bool, seed: int = 0) -> List[Dict]:
                  "derived": f"per_shard=exact;merged_pool_rel={rel:.3f}"
                             f"(tol={MERGED_POOL_TOL})"})
 
-    # ---- sharded scaling (DESIGN.md §17): own process — the forced
-    # host-device count must hit XLA before its backend initializes, and
-    # this process imported jax long ago. The child asserts bit-identity
-    # vs the vmap oracle, the sharded sync budgets, the D-invariant
-    # modeled curve, and the device-track reconciliation per point, then
-    # prints the section JSON on stdout.
-    cmd = [sys.executable,
-           str(pathlib.Path(__file__).resolve().parent /
-               "fabric_sharded.py"), "--seed", str(seed)]
-    if quick:
-        cmd.append("--quick")
-    proc = subprocess.run(cmd, capture_output=True, text=True,
-                          env=os.environ.copy())
-    if proc.returncode != 0:
-        raise RuntimeError(
-            f"fabric_sharded.py failed:\n{proc.stderr[-4000:]}")
-    sharded = json.loads(proc.stdout)
+    # ---- sharded scaling (DESIGN.md §17), in this process over the
+    # devices that exist: asserts bit-identity vs the vmap oracle, the
+    # sharded sync budgets, the D-invariant modeled curve, and the
+    # device-track reconciliation per point
+    from benchmarks import fabric_sharded
+    sharded = fabric_sharded.run(quick, seed)
     rows.append({"name": "fabric.sharded",
                  "us": 0.0,
                  "derived": ";".join(

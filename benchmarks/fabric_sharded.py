@@ -1,11 +1,15 @@
-"""Sharded fabric scaling bench (DESIGN.md §17) — subprocess half of
-``fabric_bench.py``'s ``sharded`` section.
+"""Sharded fabric scaling bench (DESIGN.md §17) — the ``sharded`` section of
+``BENCH_fabric.json``, run in-process by ``fabric_bench.py`` over the
+devices that exist (a chip belongs to one process, so nothing here starts
+a child).
 
-XLA must see the forced host-device count BEFORE its backend
-initializes, and the parent bench has long since imported jax — so this
-script runs in its own process, forces 8 host devices as its very first
-statements, and prints one JSON document to stdout for the parent to
-merge as ``BENCH_fabric.json["sharded"]``.
+Standalone, it prints the section as one JSON document. On the CPU,
+``--host-devices 8`` forces eight XLA host devices first (jax initializes
+its backend lazily, so the flag still takes effect after ``import jax``)
+to rehearse the mesh sizes without a chip:
+
+  PYTHONPATH=src JAX_PLATFORMS=cpu python benchmarks/fabric_sharded.py \
+      --quick --host-devices 8
 
 What it measures, at a fixed N=8-expander fabric under 0.8 placement
 skew with the spill path LIVE, for mesh sizes D in {1, 2, 4, 8}:
@@ -38,29 +42,25 @@ skew with the spill path LIVE, for mesh sizes D in {1, 2, 4, 8}:
 """
 from __future__ import annotations
 
-import os
+import argparse
+import json
+import sys
+import time
+from typing import Dict
 
-os.environ["XLA_FLAGS"] = (os.environ.get("XLA_FLAGS", "") +
-                           " --xla_force_host_platform_device_count=8")
+import jax
+import jax.numpy as jnp
+import numpy as np
 
-import argparse     # noqa: E402
-import json         # noqa: E402
-import sys          # noqa: E402
-import time         # noqa: E402
-from typing import Dict   # noqa: E402
-
-import jax                # noqa: E402
-import jax.numpy as jnp   # noqa: E402
-import numpy as np        # noqa: E402
-
-from repro.common.contracts import verify_sync_counters      # noqa: E402
-from repro.common.types import replace                       # noqa: E402
-from repro.core.engine.policy import POLICIES                # noqa: E402
-from repro.fabric import Fabric, WeightedInterleave          # noqa: E402
-from repro.obs import Recorder                               # noqa: E402
-from repro.obs import export as OBX                          # noqa: E402
-from repro.simx.engine import pool_cfg_for                   # noqa: E402
-from repro.simx.trace import WORKLOADS, make_rates_table, make_trace  # noqa: E402
+from repro.common.contracts import verify_sync_counters
+from repro.common.sharding import force_host_device_count
+from repro.common.types import replace
+from repro.core.engine.policy import POLICIES
+from repro.fabric import Fabric, WeightedInterleave
+from repro.obs import Recorder
+from repro.obs import export as OBX
+from repro.simx.engine import pool_cfg_for
+from repro.simx.trace import WORKLOADS, make_rates_table, make_trace
 
 N_EXP = 8
 SCALES = (1, 2, 4, 8)
@@ -113,8 +113,17 @@ def run(quick: bool, seed: int) -> Dict[str, object]:
     pipe.replay(ospn, wr, blk)
     pipe_syncs = pipe.sync_stats()["host_syncs"]
 
+    if jax.devices()[0].platform == "cpu" and \
+            jax.device_count() < max(SCALES):
+        raise RuntimeError(
+            f"the sharded sweep needs {max(SCALES)} CPU devices, "
+            f"{jax.device_count()} visible: force them before jax "
+            f"initializes (--host-devices {max(SCALES)} here, "
+            f"benchmarks/run.py does it itself)")
+    # a TPU host sweeps the chips it has
+    scales = tuple(d for d in SCALES if d <= jax.device_count())
     points: Dict[str, Dict[str, object]] = {}
-    for d in SCALES:
+    for d in scales:
         t0 = time.perf_counter()
         mk(shard_devices=d).replay(ospn, wr, blk)      # compile + warm
         compile_s = time.perf_counter() - t0
@@ -160,11 +169,11 @@ def run(quick: bool, seed: int) -> Dict[str, object]:
     modeled = {k: p["modeled_acc_per_sec"] for k, p in points.items()}
     assert len({round(v, 6) for v in modeled.values()}) == 1, modeled
 
-    # per-device track reconciliation at D=4 (obs satellite): Recorder
-    # attached, state still bit-identical, device track totals equal
-    # Fabric.device_times at rtol=1e-9, trace validates
+    # per-device track reconciliation on the largest mesh (obs
+    # satellite): Recorder attached, state still bit-identical, device
+    # track totals equal Fabric.device_times at rtol=1e-9, trace validates
     rec = Recorder()
-    fab_rec = mk(shard_devices=4, obs=rec)
+    fab_rec = mk(shard_devices=scales[-1], obs=rec)
     fab_rec.replay(ospn, wr, blk)
     assert fab_rec.state_identical(ref), "recording changed sharded state"
     dt = fab_rec.device_times()
@@ -182,11 +191,14 @@ def run(quick: bool, seed: int) -> Dict[str, object]:
         "meta": {"n_expanders": N_EXP, "n_accesses": n_accesses,
                  "n_pages": n_pages, "window": window, "reps": reps,
                  "workload": WL, "placement_skew": share,
-                 "forced_host_devices": jax.device_count(),
-                 "unit": "accesses/sec; wallclock = forced host devices "
-                         "share the physical cores (dispatch-overhead "
-                         "curve), modeled = bottleneck expander's device-"
-                         "model time (D-invariant, asserted)"},
+                 "platform": jax.devices()[0].platform,
+                 "device_count": jax.device_count(),
+                 "obs_mesh_devices": scales[-1],
+                 "unit": "accesses/sec; wallclock = host clock (on the "
+                         "CPU, forced host devices share the physical "
+                         "cores: a dispatch-overhead curve), modeled = "
+                         "bottleneck expander's device-model time "
+                         "(D-invariant, asserted)"},
         "scales": points,
         "pipelined_reference_host_syncs": pipe_syncs,
         "sync_reference_host_syncs": ref.sync_stats()["host_syncs"],
@@ -201,7 +213,12 @@ def main() -> None:
     ap = argparse.ArgumentParser()
     ap.add_argument("--quick", action="store_true")
     ap.add_argument("--seed", type=int, default=0)
+    ap.add_argument("--host-devices", type=int, default=0, metavar="N",
+                    help="force N XLA host devices (CPU rehearsal of the "
+                         "mesh sizes; never needed on a chip)")
     args = ap.parse_args()
+    if args.host_devices:
+        force_host_device_count(args.host_devices)
     payload = run(args.quick, args.seed)
     json.dump(payload, sys.stdout)
     sys.stdout.write("\n")

@@ -9,6 +9,7 @@ Usage:
 from __future__ import annotations
 
 import argparse
+import os
 import sys
 import traceback
 
@@ -24,6 +25,18 @@ def main() -> None:
     args = ap.parse_args()
     quick = not args.full
     only = [s.strip() for s in args.only.split(",") if s.strip()]
+
+    # the fabric suite sweeps the sharded fabric over D in {1, 2, 4, 8}; on
+    # the CPU that needs eight host devices, forced before jax initializes
+    # its backend (the flag reaches only the CPU platform, so a TPU host
+    # still sweeps its own chips)
+    if "xla_force_host_platform_device_count" not in os.environ.get(
+            "XLA_FLAGS", ""):
+        from repro.common.sharding import force_host_device_count
+        force_host_device_count(8)
+
+    from repro.common.compile_cache import enable_compile_cache
+    enable_compile_cache()
 
     from benchmarks import (fabric_bench, kernel_bench, lint_bench,
                             paper_figs, serve_bench, simx_bench,

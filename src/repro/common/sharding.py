@@ -50,9 +50,11 @@ def expander_mesh(n_devices: int) -> Mesh:
     import numpy as _np
     devs = jax.devices()
     if n_devices > len(devs):
+        hint = ("" if devs[0].platform == "tpu" else
+                "; call force_host_device_count before jax initializes")
         raise ValueError(
-            f"expander_mesh({n_devices}) but only {len(devs)} devices "
-            "visible; call force_host_device_count before jax initializes")
+            f"expander_mesh({n_devices}) but only {len(devs)} "
+            f"{devs[0].platform} devices visible{hint}")
     return Mesh(_np.asarray(devs[:n_devices]), (EXPANDER_AXIS,))
 
 # Default rule table: FSDP over "data", tensor parallel over "model",

@@ -55,18 +55,28 @@ def bytes_to_u16(b: jnp.ndarray) -> jnp.ndarray:
     return pairs[..., 0] | (pairs[..., 1] << jnp.uint16(8))
 
 
+def u32_to_bytes(u: jnp.ndarray) -> jnp.ndarray:
+    """uint32[..., N] -> uint8[..., 4N] little-endian."""
+    u = u.astype(jnp.uint32)
+    out = [((u >> jnp.uint32(s)) & jnp.uint32(0xFF)).astype(jnp.uint8)
+           for s in (0, 8, 16, 24)]
+    return jnp.stack(out, axis=-1).reshape(u.shape[:-1] + (u.shape[-1] * 4,))
+
+
+def bytes_to_u32(b: jnp.ndarray) -> jnp.ndarray:
+    """uint8[..., 4N] -> uint32[..., N] little-endian."""
+    quads = b.reshape(b.shape[:-1] + (b.shape[-1] // 4, 4)).astype(jnp.uint32)
+    return (quads[..., 0] | (quads[..., 1] << 8) | (quads[..., 2] << 16)
+            | (quads[..., 3] << 24))
+
+
 def f32_to_bytes(x: jnp.ndarray) -> jnp.ndarray:
-    u = jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32)
-    out = []
-    for s in (0, 8, 16, 24):
-        out.append(((u >> jnp.uint32(s)) & jnp.uint32(0xFF)).astype(jnp.uint8))
-    return jnp.stack(out, axis=-1).reshape(x.shape[:-1] + (x.shape[-1] * 4,))
+    return u32_to_bytes(
+        jax.lax.bitcast_convert_type(x.astype(jnp.float32), jnp.uint32))
 
 
 def bytes_to_f32(b: jnp.ndarray) -> jnp.ndarray:
-    quads = b.reshape(b.shape[:-1] + (b.shape[-1] // 4, 4)).astype(jnp.uint32)
-    u = quads[..., 0] | (quads[..., 1] << 8) | (quads[..., 2] << 16) | (quads[..., 3] << 24)
-    return jax.lax.bitcast_convert_type(u.astype(jnp.uint32), jnp.float32)
+    return jax.lax.bitcast_convert_type(bytes_to_u32(b), jnp.float32)
 
 
 # ---------------------------------------------------------------------------

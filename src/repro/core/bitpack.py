@@ -48,15 +48,24 @@ def unpack8(b: jnp.ndarray) -> jnp.ndarray:
     return jax.lax.bitcast_convert_type(b.astype(jnp.uint8), jnp.int8)
 
 
+def block_scale(amax: jnp.ndarray, bits: int
+                ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """(scale, 1/scale) of blocks with max magnitude ``amax``. The one
+    divide of the codec: the Pallas kernels receive both from XLA instead
+    of dividing in Mosaic, whose reciprocal need not round as XLA's does."""
+    qmax = float(2 ** (bits - 1) - 1)
+    scale = jnp.where(amax > 0, amax * jnp.float32(1.0 / qmax), 1.0)
+    return scale, jnp.float32(1.0) / scale
+
+
 def quantize_block(x: jnp.ndarray, bits: int) -> tuple[jnp.ndarray, jnp.ndarray]:
     """Symmetric per-block quantization. Returns (codes int8, scale f32).
 
-    Uses explicit reciprocal multiplies (never divides) so the Pallas kernels
-    and this oracle are bit-identical regardless of XLA's div lowering."""
+    Multiplies by the reciprocal scale (never divides per value), so the
+    Pallas kernels, given ``block_scale`` from XLA, are bit-identical."""
     qmax = float(2 ** (bits - 1) - 1)
     amax = jnp.max(jnp.abs(x.astype(jnp.float32)), axis=-1, keepdims=True)
-    scale = jnp.where(amax > 0, amax * jnp.float32(1.0 / qmax), 1.0)
-    recip = jnp.float32(1.0) / scale
+    scale, recip = block_scale(amax, bits)
     q = jnp.clip(jnp.round(x.astype(jnp.float32) * recip), -qmax - 1, qmax)
     return q.astype(jnp.int8), scale[..., 0]
 

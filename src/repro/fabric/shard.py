@@ -51,7 +51,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 from jax import lax
-from jax.experimental.shard_map import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.common.sharding import EXPANDER_AXIS
@@ -269,10 +268,10 @@ def replay_step(mesh: Mesh, cfg: PoolConfig, policy: Policy,
         return pools_l, times_l, stats_l.free_units
 
     outs = (ax, ax) if not need_free else (ax, ax, ax)
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(ax, ax, ax, ax, ax, ax, P()),
-        out_specs=outs, check_rep=False))
+        out_specs=outs, check_vma=False))
 
 
 @functools.lru_cache(maxsize=None)
@@ -326,11 +325,11 @@ def boundary_step(mesh: Mesh, cfg: PoolConfig, policy: Policy,
                 pools_l.cfree.top, pools_l.gfree.top,
                 pages, srcs, dsts, urgent, moved)
 
-    return jax.jit(shard_map(
+    return jax.jit(jax.shard_map(
         local, mesh=mesh,
         in_specs=(ax, ax, ax, ax, ax, ax, P(), P()),
         out_specs=(ax, ax, ax, ax, ax, ax, P(), P(), P(), P(), P()),
-        check_rep=False))
+        check_vma=False))
 
 
 def shard_pools(pools: Pool, mesh: Mesh) -> Pool:

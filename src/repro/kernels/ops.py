@@ -1,82 +1,56 @@
-"""Public jit'd wrappers around the Pallas kernels.
+"""Public wrappers around the Pallas kernels.
 
-``INTERPRET`` defaults to True off-TPU (this container validates kernels with
-the Pallas interpreter); on a real TPU backend the compiled kernels run. The
-wrappers also adapt shapes to/from the flat layouts used elsewhere
-(core.compressor.quantize_blocks et al.): blocks smaller than a 256-value
-row are grouped (e.g. four 64-value head-dim blocks per row) so the kernels
-always see lane-aligned rows.
+On a TPU the compiled Mosaic kernels run; elsewhere the Pallas interpreter
+runs the same kernel bodies (``qpack.resolve_interpret``, decided at call
+time). The wrappers adapt shapes to and from the flat layouts used
+elsewhere (core.compressor.quantize_blocks et al.): every block of a
+``[..., N]`` tensor becomes one kernel row, padded to whole ``TILE``s.
 """
 from __future__ import annotations
 
-import jax
+import math
+
 import jax.numpy as jnp
 
 from repro.kernels import flash_attn as _fa
 from repro.kernels import kvc_attn as _ka
 from repro.kernels import qpack as _qp
 
-INTERPRET = jax.default_backend() != "tpu"
 
-_ROW = 256   # minimum kernel row width (nibble pairs stay lane-aligned)
-
-
-def _row_blocks(block: int) -> int:
-    """Blocks grouped per kernel row (1 for block >= 256)."""
-    if block % _ROW == 0:
-        return 1
-    assert _ROW % block == 0 and block % 2 == 0, block
-    return _ROW // block
+def _pad_rows(a: jnp.ndarray, fill=0) -> jnp.ndarray:
+    """Pad the leading (block) axis to a whole number of kernel tiles."""
+    pad = (-a.shape[0]) % _qp.TILE
+    if not pad:
+        return a
+    return jnp.concatenate(
+        [a, jnp.full((pad,) + a.shape[1:], fill, a.dtype)], axis=0)
 
 
 def qpack_encode(x: jnp.ndarray, bits: int = 4, block: int = 512,
                  interpret: bool | None = None):
     """x[..., N] -> (codes uint8[..., N*bits/8], scales f32[..., N/block]).
     Shape-compatible with core.compressor.quantize_blocks."""
-    if interpret is None:
-        interpret = INTERPRET
     lead = x.shape[:-1]
     n = x.shape[-1]
     nblk = n // block
-    total_blocks = int(jnp.prod(jnp.asarray(lead + (nblk,)))) if lead else nblk
-    rb = _row_blocks(block)
-    # pad block count to whole kernel tiles of whole rows
-    pad = (-total_blocks) % (_qp.TILE * rb)
-    x2 = x.reshape(total_blocks, block)
-    if pad:
-        x2 = jnp.concatenate([x2, jnp.zeros((pad, block), x.dtype)], axis=0)
-    rows = (total_blocks + pad) // rb
-    codes, scales = _qp.qpack_encode_2d(x2.reshape(rows, rb * block),
-                                        bits=bits, block=block,
-                                        interpret=interpret)
-    codes = codes.reshape(rows * rb, block * bits // 8)[:total_blocks]
-    codes = codes.reshape(lead + (n * bits // 8,))
-    scales = scales.reshape(rows * rb)[:total_blocks].reshape(lead + (nblk,))
-    return codes, scales
+    total = math.prod(lead) * nblk
+    codes, scales = _qp.qpack_encode_2d(
+        _pad_rows(x.reshape(total, block)), bits=bits, interpret=interpret)
+    codes = codes[:total].reshape(lead + (n * bits // 8,))
+    return codes, scales[:total].reshape(lead + (nblk,))
 
 
 def qpack_decode(codes: jnp.ndarray, scales: jnp.ndarray, bits: int = 4,
                  block: int = 512, dtype=jnp.bfloat16,
                  interpret: bool | None = None) -> jnp.ndarray:
-    if interpret is None:
-        interpret = INTERPRET
     lead = scales.shape[:-1]
     nblk = scales.shape[-1]
-    bp = block * bits // 8
-    total_blocks = int(jnp.prod(jnp.asarray(lead + (nblk,)))) if lead else nblk
-    rb = _row_blocks(block)
-    pad = (-total_blocks) % (_qp.TILE * rb)
-    c2 = codes.reshape(total_blocks, bp)
-    s2 = scales.reshape(total_blocks, 1)
-    if pad:
-        c2 = jnp.concatenate([c2, jnp.zeros((pad, bp), jnp.uint8)], axis=0)
-        s2 = jnp.concatenate([s2, jnp.ones((pad, 1), jnp.float32)], axis=0)
-    rows = (total_blocks + pad) // rb
-    x = _qp.qpack_decode_2d(c2.reshape(rows, rb * bp),
-                            s2.reshape(rows, rb), bits=bits, block=block,
-                            out_dtype=dtype, interpret=interpret)
-    x = x.reshape(rows * rb, block)[:total_blocks]
-    return x.reshape(lead + (nblk * block,))
+    total = math.prod(lead) * nblk
+    x = _qp.qpack_decode_2d(
+        _pad_rows(codes.reshape(total, block * bits // 8)),
+        _pad_rows(scales.reshape(total), 1.0), bits=bits, out_dtype=dtype,
+        interpret=interpret)
+    return x[:total].reshape(lead + (nblk * block,))
 
 
 def qpack_fused_encode(x: jnp.ndarray, *, tol4: float = 0.10,
@@ -86,14 +60,9 @@ def qpack_fused_encode(x: jnp.ndarray, *, tol4: float = 0.10,
                        interpret: bool | None = None):
     """Fused demote over blocks x [T, V]: pads T to the kernel tile and
     returns (dense uint8[T, 2V], rates int32[T], quanta int32[T])."""
-    if interpret is None:
-        interpret = INTERPRET
-    t, v = x.shape
-    pad = (-t) % _qp.TILE
-    if pad:
-        x = jnp.concatenate([x, jnp.zeros((pad, v), x.dtype)], axis=0)
+    t = x.shape[0]
     dense, rates, qnt = _qp.qpack_fused_encode_2d(
-        x, tol4=tol4, tol8=tol8, lossless=lossless,
+        _pad_rows(x), tol4=tol4, tol8=tol8, lossless=lossless,
         zero_elision=zero_elision, quanta=tuple(quanta),
         interpret=interpret)
     return dense[:t], rates[:t], qnt[:t]
@@ -102,17 +71,9 @@ def qpack_fused_encode(x: jnp.ndarray, *, tol4: float = 0.10,
 def qpack_fused_decode(dense: jnp.ndarray, rates: jnp.ndarray, *,
                        interpret: bool | None = None) -> jnp.ndarray:
     """Fused promote over dense blocks [T, 2V] + rates [T] -> bf16 [T, V]."""
-    if interpret is None:
-        interpret = INTERPRET
-    t, nb = dense.shape
-    pad = (-t) % _qp.TILE
-    if pad:
-        dense = jnp.concatenate(
-            [dense, jnp.zeros((pad, nb), jnp.uint8)], axis=0)
-        rates = jnp.concatenate(
-            [rates, jnp.zeros((pad,), rates.dtype)], axis=0)
-    out = _qp.qpack_fused_decode_2d(dense, rates, interpret=interpret)
-    return out[:t]
+    out = _qp.qpack_fused_decode_2d(_pad_rows(dense), _pad_rows(rates),
+                                    interpret=interpret)
+    return out[:dense.shape[0]]
 
 
 def kvc_decode_attention(q, k_codes, k_scales, v_codes, v_scales, lengths, *,
@@ -120,11 +81,13 @@ def kvc_decode_attention(q, k_codes, k_scales, v_codes, v_scales, lengths, *,
                          t_blk: int = 128) -> jnp.ndarray:
     return _ka.kvc_decode_attention(
         q, k_codes, k_scales, v_codes, v_scales, lengths, bits=bits,
-        sm_scale=sm_scale, t_blk=t_blk, interpret=INTERPRET)
+        sm_scale=sm_scale, t_blk=t_blk,
+        interpret=_qp.resolve_interpret(None))
 
 
 def flash_attention(q, k, v, *, causal: bool = True,
                     sm_scale: float | None = None, tq: int = 128,
                     tk: int = 128) -> jnp.ndarray:
     return _fa.flash_attention(q, k, v, causal=causal, sm_scale=sm_scale,
-                               tq=tq, tk=tk, interpret=INTERPRET)
+                               tq=tq, tk=tk,
+                               interpret=_qp.resolve_interpret(None))

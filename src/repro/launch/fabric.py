@@ -57,6 +57,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.core.engine import batch as B
 from repro.core.engine import state as S
 from repro.core.engine.policy import POLICIES
@@ -120,6 +121,7 @@ def main() -> None:
                          "cycled across expanders — e.g. 'default,gen4' "
                          "makes an alternating mixed-generation fleet")
     args = ap.parse_args()
+    enable_compile_cache()
 
     profiles = [p.strip() for p in args.device_profile.split(",") if p.strip()]
     unknown = [p for p in profiles
@@ -163,7 +165,9 @@ def main() -> None:
             ap.error(f"--devices {args.devices} but only "
                      f"{jax.device_count()} XLA devices visible")
         owners = FS.device_of_expander(n, args.devices)
-        print(f"mesh: {args.devices} forced host device(s), axis "
+        kind = ("device(s)" if jax.devices()[0].platform == "tpu"
+                else "forced host device(s)")
+        print(f"mesh: {args.devices} {kind}, axis "
               f"'expander', {n} expanders "
               f"({n // args.devices} per device)")
         for d in range(args.devices):

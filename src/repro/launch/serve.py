@@ -13,6 +13,7 @@ import time
 import jax
 import numpy as np
 
+from repro.common.compile_cache import enable_compile_cache
 from repro.common.types import ServeConfig
 from repro.configs import describe, get_config, get_reduced
 from repro.models import transformer as T
@@ -43,6 +44,7 @@ def main() -> None:
                          "trace_event export there plus a metrics.json "
                          "sibling")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cfg = get_reduced(args.arch) if args.reduced else get_config(args.arch)
     print(describe(cfg))

@@ -89,6 +89,25 @@ def test_fused_encode_decode_all_rates_bit_identical(coloc):
     np.testing.assert_array_equal(got, want)
 
 
+@pytest.mark.parametrize("lossless", [False, True])
+@pytest.mark.parametrize("v", [128, 256])
+def test_fused_encode_narrow_blocks_bit_identical(v, lossless):
+    """Blocks whose value planes are narrower than a vector register (16
+    and 32 lanes) still match the oracle's rates and dense rows, and the
+    rate comes from the oracle's divide."""
+    xs = _blocks_all_rates(v, 16)
+    cfg = PoolConfig(lossless=lossless)
+    rates = comp.select_rate(xs, cfg)
+    dense = jax.vmap(comp._encode_block_dense)(xs, rates)
+    got_d, got_r, got_q = qp.qpack_fused_encode_2d(
+        xs, lossless=lossless, quanta=comp.quanta_per_rate(v),
+        interpret=True)
+    _assert_trees_equal((got_d, got_r), (dense, rates))
+    np.testing.assert_array_equal(
+        np.asarray(got_q),
+        np.asarray(comp.block_quanta_table(v))[np.asarray(rates)])
+
+
 def test_fused_single_page_dispatch_matches_batched():
     cfg_k = PoolConfig(lossless=True, compress_impl="kernel")
     x = _blocks_all_rates(cfg_k.vals_per_block, 4).reshape(-1)
@@ -140,13 +159,28 @@ def test_quantize_blocks_fast_parity():
         np.testing.assert_array_equal(np.asarray(sj), np.asarray(sk))
 
 
+@pytest.mark.parametrize("bits", [4, 8])
+def test_quantize_blocks_fast_kernel_under_jit(bits):
+    """The kernel path traces under jit (the server's lane demotion is a
+    jitted caller): block counts come from static shapes, never from a
+    traced value."""
+    x = (jax.random.normal(KEY, (2, 3, 16, 128)) * 2).astype(jnp.float32)
+    fast = jax.jit(lambda a: comp.quantize_blocks_fast(a, bits, 128,
+                                                       impl="kernel"))
+    cj, sj = comp.quantize_blocks(x, bits, 128)
+    ck, sk = fast(x)
+    np.testing.assert_array_equal(np.asarray(cj), np.asarray(ck))
+    np.testing.assert_array_equal(np.asarray(sj), np.asarray(sk))
+
+
 def test_interpret_auto_detect():
     """Satellite 1: interpret defaults to backend detection, not True."""
     on_tpu = jax.default_backend() == "tpu"
     assert qp.resolve_interpret(None) == (not on_tpu)
     assert qp.resolve_interpret(True) is True
     assert qp.resolve_interpret(False) is False
-    assert kops.INTERPRET == (not on_tpu)
+    # the wrappers decide per call, never from a backend query at import
+    assert not hasattr(kops, "INTERPRET")
 
 
 def test_resolve_impl_dispatch():

@@ -4,6 +4,8 @@ consistency under preemption, the batched scheduler's host-sync contract
 (one sync per decode step), shadowed lane re-preemption (zero bytes), and
 the padded-prefill regression (padded rows must decode identically to
 unpadded ones)."""
+import dataclasses
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -51,6 +53,27 @@ def test_oversubscription_preempts_and_finishes(params):
     # 5 requests through 2 lanes must have demoted someone
     assert eng.counters["demotions"] >= 1
     assert eng.counters["promotions"] >= 5
+
+
+def test_preemption_through_qpack_kernel(params):
+    """Lane demotion routed through the Pallas qpack encode (interpret
+    mode off-TPU, the compiled kernel on a TPU) inside the jitted demote:
+    same tokens and same parked codes as the jnp quantizer."""
+    runs = {}
+    for impl in ("kernel", "jnp"):
+        eng = Engine(CFG, dataclasses.replace(SCFG, quantize_impl=impl),
+                     params, max_len=128)
+        rids = [eng.submit(_prompt(i), max_new_tokens=6) for i in range(3)]
+        eng.run_until_done(max_steps=400)
+        assert eng.counters["demotions"] >= 1
+        assert all(eng.requests[r].state == DONE for r in rids)
+        runs[impl] = eng
+    k, j = runs["kernel"], runs["jnp"]
+    assert k.counters == j.counters
+    for rid in k.requests:
+        assert k.result(rid) == j.result(rid)
+    np.testing.assert_array_equal(np.asarray(k.cache["k_codes"]),
+                                  np.asarray(j.cache["k_codes"]))
 
 
 def test_preemption_consistency(params):
@@ -209,7 +232,6 @@ def test_modeled_time_prices_motion_and_syncs(params):
     preempt/resume byte and host-sync counters into modeled seconds —
     per-expander on a fabric-striped config, reconciling with the
     expander_stats byte totals, and monotone in the demotion traffic."""
-    import dataclasses
     from repro.simx import time as TM
 
     eng = Engine(CFG, dataclasses.replace(SCFG, n_expanders=2), params,
