@@ -54,9 +54,8 @@ def _workload(rng, vocab: int, n_requests: int):
             for i in range(n_requests)]
 
 
-def _serve(engine_cls, cfg, scfg, params, prompts, new_tokens, max_len,
-           obs=None):
-    eng = engine_cls(cfg, scfg, params, max_len=max_len, obs=obs)
+def _serve(engine_cls, cfg, scfg, params, prompts, new_tokens, max_len):
+    eng = engine_cls(cfg, scfg, params, max_len=max_len)
     rids = [eng.submit(p, new_tokens) for p in prompts]
     t0 = time.perf_counter()
     eng.run_until_done(max_steps=4000)
@@ -135,43 +134,12 @@ def run(quick: bool, seed: int = 0) -> List[Dict]:
                                            max_len)
     assert shadow_bytes == 0, shadow_bytes
 
-    # -- telemetry piggyback A/B (DESIGN.md §16): the batched run repeated
-    # with an obs.Recorder attached. Asserted: the engine's counters are
-    # identical to the recording-off run (the recorder only consumes the
-    # host values the step's single fetch already produced), the declared
-    # one-sync-per-step contract still holds with the recorder draining
-    # every step, and the exported Perfetto trace validates. Wall-clock
-    # overhead is recorded (warm A/B) — the ≤5% acceptance number.
-    from repro.obs import Recorder
-    from repro.obs import export as OBX
-    rec = Recorder()
-    re_, dt_r = _serve(Engine, cfg, scfg, params, prompts, new_tokens,
-                       max_len, obs=rec)
-    assert re_.counters == be.counters, \
-        "recording changed the engine's counters"
-    verify_sync_counters(Engine.step, re_.counters["steps"],
-                         re_.counters["step_syncs"],
-                         what="recorder attached")
-    trace = OBX.build_trace(rec)
-    errors = OBX.validate_trace(trace)
-    assert not errors, errors
-    obs_ab = {
-        "counters_identical": True,
-        "step_syncs_with_recorder": re_.counters["step_syncs"],
-        "steps_recorded": len(rec.steps),
-        "events_recorded": len(rec.serve_events),
-        "trace_events": len(trace["traceEvents"]),
-        "trace_valid": True,
-        "wallclock_overhead_ratio": dt_r / max(dt_b, 1e-12),
-    }
-
     payload = {
         "meta": {**run_manifest(seed=seed),
                  "arch": ARCH, "lanes": scfg.max_running,
                  "requests": n_requests, "new_tokens": new_tokens,
                  "max_len": max_len, "quick": quick,
                  "unit": "decode tokens/sec, admission included"},
-        "obs": obs_ab,
         "serial_tok_per_sec": tok_s,
         "batched_tok_per_sec": tok_b,
         "speedup_batched_over_serial": tok_b / max(tok_s, 1e-9),
@@ -224,8 +192,4 @@ def run(quick: bool, seed: int = 0) -> List[Dict]:
                     f"batched={mb['modeled_s_per_step'] * 1e6:.2f}us;"
                     f"striped2x={mf['modeled_s_per_step'] * 1e6:.2f}us;"
                     f"modeled_x={payload['modeled']['modeled_speedup_batched_over_serial']:.2f}"},
-        {"name": "serve.obs.ab", "us": dt_r * 1e6,
-         "derived": f"overhead=x{obs_ab['wallclock_overhead_ratio']:.3f};"
-                    f"counters_identical=True;"
-                    f"events={obs_ab['trace_events']}"},
     ]

@@ -51,6 +51,7 @@ from repro.core.engine.state import (C_ACT_WR, C_DATA_RD, C_DATA_WR,
                                      C_HOST_RD, C_HOST_WR, C_MC_HIT,
                                      C_MC_MISS, C_META_RD, C_META_WR,
                                      C_ZERO_SERVED, Pool, bump)
+from repro.obs import spans
 
 DEFAULT_WINDOW = 32
 SLOW_FORI = 8      # slow accesses handled per window before the while loop
@@ -155,36 +156,41 @@ def _window_step(pool: Pool, cfg: PoolConfig, policy: Policy, xs,
     else:
         extra = min(window // 4, max(2, cfg.demote_watermark // 2))
         budget = max(4, window // 4)
-    pool = ops.demote_if_needed(pool, cfg, policy, max_demotes=budget,
-                                watermark=cfg.demote_watermark + extra)
+    with spans.scope(spans.TOPUP):
+        pool = ops.demote_if_needed(pool, cfg, policy, max_demotes=budget,
+                                    watermark=cfg.demote_watermark + extra)
 
     # phase 1: classification snapshot (phase 2 never touches metadata)
-    fast, is_zero, is_hot = _classify_window(pool, cfg, ospns, writes, blocks)
+    with spans.scope(spans.CLASSIFY):
+        fast, is_zero, is_hot = _classify_window(pool, cfg, ospns, writes,
+                                                 blocks)
 
     # phase 2: vectorized metadata probes + activity updates for the window
-    pool = _mcache_window(pool, cfg, policy, ospns)
+    with spans.scope(spans.MCACHE):
+        pool = _mcache_window(pool, cfg, policy, ospns)
 
-    # vectorized accounting for the fast accesses
-    fast_rd = fast & (~writes)
-    fast_wr = fast & writes
-    n_fast_rd = jnp.sum(fast_rd)
-    n_fast_wr = jnp.sum(fast_wr)
-    counters = bump(pool.counters, C_HOST_RD, n_fast_rd)
-    counters = bump(counters, C_HOST_WR, n_fast_wr)
-    counters = policy.on_host_access(counters, False, n=n_fast_rd)
-    counters = policy.on_host_access(counters, True, n=n_fast_wr)
-    counters = bump(counters, C_ZERO_SERVED, jnp.sum(fast_rd & is_zero))
-    counters = bump(counters, C_DATA_RD,
-                    jnp.sum(fast_rd & is_hot) * (cfg.block_bytes // 64))
-    # fast (hot, dirty) writes: data write + metadata write-back, no
-    # metadata *change* — see _classify_window
-    counters = bump(counters, C_DATA_WR, n_fast_wr * (cfg.block_bytes // 64))
-    if cfg.compact:
-        wr_widths = n_fast_wr
-    else:
-        wr_widths = jnp.sum(jnp.where(fast_wr, 1 + (ospns & 1), 0))
-    counters = bump(counters, C_META_WR, wr_widths)
-    pool = pool._replace(counters=counters)
+        # vectorized accounting for the fast accesses
+        fast_rd = fast & (~writes)
+        fast_wr = fast & writes
+        n_fast_rd = jnp.sum(fast_rd)
+        n_fast_wr = jnp.sum(fast_wr)
+        counters = bump(pool.counters, C_HOST_RD, n_fast_rd)
+        counters = bump(counters, C_HOST_WR, n_fast_wr)
+        counters = policy.on_host_access(counters, False, n=n_fast_rd)
+        counters = policy.on_host_access(counters, True, n=n_fast_wr)
+        counters = bump(counters, C_ZERO_SERVED, jnp.sum(fast_rd & is_zero))
+        counters = bump(counters, C_DATA_RD,
+                        jnp.sum(fast_rd & is_hot) * (cfg.block_bytes // 64))
+        # fast (hot, dirty) writes: data write + metadata write-back, no
+        # metadata *change* — see _classify_window
+        counters = bump(counters, C_DATA_WR,
+                        n_fast_wr * (cfg.block_bytes // 64))
+        if cfg.compact:
+            wr_widths = n_fast_wr
+        else:
+            wr_widths = jnp.sum(jnp.where(fast_wr, 1 + (ospns & 1), 0))
+        counters = bump(counters, C_META_WR, wr_widths)
+        pool = pool._replace(counters=counters)
 
     # phase 3: serialized replay of the slow accesses only — fast accesses
     # pay no per-access control flow at all. The first SLOW_FORI slow
@@ -202,6 +208,19 @@ def _window_step(pool: Pool, cfg: PoolConfig, policy: Policy, xs,
     # isolated by tests/test_fabric_sharded.py's bit-identity suite),
     # while the unrolled form is bit-exact there. Single-device paths
     # keep the loops: same op sequence, smaller HLO.
+    with spans.scope(spans.DRAIN):
+        pool = _drain_slow(pool, cfg, policy, xs, fast, zero_block,
+                           unroll_slow)
+    return pool, None
+
+
+def _drain_slow(pool: Pool, cfg: PoolConfig, policy: Policy, xs, fast,
+                zero_block, unroll_slow: bool) -> Pool:
+    """Phase 3 of ``_window_step``: the window's slow accesses, in order,
+    through the serial per-access bodies."""
+    ospns, writes, blocks = xs
+    window = ospns.shape[0]
+    per_access = cfg.demote_cadence == "access"
     n_slow = jnp.sum(~fast)
     slow_order = jnp.argsort(jnp.where(fast, window + jnp.arange(window),
                                        jnp.arange(window)))
@@ -228,7 +247,7 @@ def _window_step(pool: Pool, cfg: PoolConfig, policy: Policy, xs,
             pool = jax.lax.cond(i < n_slow,
                                 functools.partial(process, slow_order[i]),
                                 lambda q: q, pool)
-        return pool, None
+        return pool
 
     k_fori = min(SLOW_FORI, window)
     pool = jax.lax.fori_loop(
@@ -248,7 +267,7 @@ def _window_step(pool: Pool, cfg: PoolConfig, policy: Policy, xs,
 
     _, pool = jax.lax.while_loop(slow_cond, slow_body,
                                  (jnp.asarray(k_fori, jnp.int32), pool))
-    return pool, None
+    return pool
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))

@@ -8,6 +8,7 @@ runs the per-lane baseline for comparison).
 from __future__ import annotations
 
 import argparse
+import contextlib
 import time
 
 import jax
@@ -37,12 +38,10 @@ def main() -> None:
                          "scheduler")
     ap.add_argument("--paper-mode", action="store_true",
                     help="promote-then-read instead of fused dequant attn")
-    ap.add_argument("--trace", default=None, metavar="OUT.trace.json",
-                    help="attach a repro.obs.Recorder (samples ride the "
-                         "engine's single per-step fetch — zero extra "
-                         "syncs, asserted below), write the Perfetto "
-                         "trace_event export there plus a metrics.json "
-                         "sibling")
+    ap.add_argument("--trace", default=None, metavar="DIR",
+                    help="profile the run into DIR (jax.profiler.trace): "
+                         "the engine's spans (repro.obs.spans) beside the "
+                         "device's programs, for Perfetto or TensorBoard")
     args = ap.parse_args()
     enable_compile_cache()
 
@@ -53,19 +52,18 @@ def main() -> None:
                        fused_dequant_attention=not args.paper_mode)
     params, _ = T.init_params(jax.random.PRNGKey(0), cfg)
     engine_cls = SerialEngine if args.serial else Engine
-    rec = None
-    if args.trace:
-        from repro.obs import Recorder
-        rec = Recorder()
-    eng = engine_cls(cfg, scfg, params, max_len=args.max_len, obs=rec)
+    eng = engine_cls(cfg, scfg, params, max_len=args.max_len)
 
     rng = np.random.default_rng(0)
     def plen(i):
         return (8 + 4 * (i % 5)) if args.vary_prompts else args.prompt_len
     rids = [eng.submit(list(rng.integers(1, cfg.vocab_size, plen(i))),
                        args.new_tokens) for i in range(args.requests)]
+    profile = (jax.profiler.trace(args.trace) if args.trace
+               else contextlib.nullcontext())
     t0 = time.time()
-    eng.run_until_done(max_steps=5000)
+    with profile:
+        eng.run_until_done(max_steps=5000)
     dt = time.time() - t0
     done = sum(eng.requests[r].state == "done" for r in rids)
     c = eng.counters
@@ -84,19 +82,8 @@ def main() -> None:
           f"{mt['modeled_s_per_step'] * 1e6:.2f}us/step "
           f"(sync={mt['sync_s'] * 1e3:.3f}ms, motion bottleneck="
           f"{max(mt['motion_s_per_expander']) * 1e6:.2f}us)")
-    if rec is not None:
-        from repro.obs import export as OBX
-        if not args.serial:   # serial baseline syncs once per lane per step
-            assert c["step_syncs"] == c["steps"], \
-                "recording changed the per-step sync budget"
-        OBX.write_trace(rec, args.trace)
-        mpath = (args.trace[: -len(".trace.json")] if
-                 args.trace.endswith(".trace.json") else args.trace) \
-            + ".metrics.json"
-        OBX.write_metrics(rec, mpath)
-        print(f"trace: {args.trace} (+ {mpath}); "
-              f"{len(rec.steps)} steps, {len(rec.serve_events)} events "
-              f"recorded at zero extra syncs (asserted)")
+    if args.trace:
+        print(f"trace: profile under {args.trace}")
     for rid in rids[:3]:
         print(f"  req {rid}: {eng.result(rid)}")
 

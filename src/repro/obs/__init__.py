@@ -1,22 +1,29 @@
 """Zero-extra-sync telemetry (DESIGN.md §16).
 
-The observability layer's collection path adds NO host syncs: every
-sample rides inside a payload the hot paths already fetch under their
-declared ``@sync_contract`` budgets — the fabric's fused per-segment
-stats fetch (``Fabric._fetch_view``), the per-epoch moved-pages fetch
-(``Fabric._commit_epoch``), and the serving engine's single per-step
-``(tok, done, ref, pos)`` fetch (``serve.Engine.step``). The
-:class:`Recorder` accumulates those piggybacked samples host-side into a
-metrics registry (counters / gauges / histograms keyed by
-``state.COUNTER_NAMES`` — never integer literals, R3 stays clean) and a
-structured event log; exporters turn them into a Chrome/Perfetto
-``trace_event`` timeline, a ``metrics.json`` snapshot, and the run
-manifest stamped into every BENCH_*.json.
+Two parts, one per form of the program:
 
-Instrumentation is opt-in: ``obs=None`` (the default everywhere) is the
+* **Spans and scopes** (``repro.obs.spans``): the names the program emits
+  on the profiler's clock. The replay window step's phases are device
+  scopes (``jax.named_scope``); the serve engine's phases are host spans
+  (``jax.profiler.TraceAnnotation``), written only while a profiler runs.
+  The serve engine's counts live in ``Engine.counters``.
+* **The fabric's Recorder**: every sample rides inside a payload the
+  fabric already fetches under its declared ``@sync_contract`` budgets —
+  the fused per-segment stats fetch (``Fabric._fetch_view``) and the
+  per-epoch moved-pages fetch (``Fabric._commit_epoch``). The
+  :class:`Recorder` accumulates those piggybacked samples host-side into a
+  metrics registry (counters / gauges / histograms keyed by
+  ``state.COUNTER_NAMES`` — never integer literals, R3 stays clean) and a
+  structured event log; exporters turn them into a Chrome/Perfetto
+  ``trace_event`` timeline of the *modeled* pipeline pricing, a
+  ``metrics.json`` snapshot, and the run manifest stamped into every
+  BENCH_*.json.
+
+Recording is opt-in: ``obs=None`` (the default everywhere) is the
 recording-off path, bit-identical in pool/counter state to recording-on
 (tests/test_obs.py pins this), and the analyzer's R6 rule enforces that
-telemetry is emitted ONLY through these piggyback drains.
+telemetry is emitted ONLY through these piggyback drains. ``spans`` is not
+imported here: ``repro.obs`` loads on jax-free hosts.
 """
 from repro.obs.manifest import manifest
 from repro.obs.recorder import Recorder

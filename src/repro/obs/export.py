@@ -24,10 +24,10 @@ syncs.
 
 Events follow the Chrome ``trace_event`` JSON format: ``X`` complete
 events (ts/dur in microseconds), ``M`` metadata naming processes and
-tracks, ``C`` counter events for freelist headroom, ``i`` instants for
-admissions. ``validate_trace`` checks the structural contract tests pin:
-required keys per phase, per-track monotone timestamps, and proper span
-nesting (overlapping spans on one track must nest).
+tracks, ``C`` counter events for freelist headroom. ``validate_trace``
+checks the structural contract tests pin: required keys per phase,
+per-track monotone timestamps, and proper span nesting (overlapping spans
+on one track must nest).
 
 jax and the timing model are imported lazily — ``repro.obs`` stays
 importable on jax-free hosts (manifest stamping from the lint bench).
@@ -44,7 +44,6 @@ from repro.obs.manifest import manifest
 from repro.obs.recorder import Recorder
 
 _FABRIC_PID = 1
-_SERVE_PID = 2
 _DEVICE_TID = 1000     # per-XLA-device shard tracks start here
 
 
@@ -222,75 +221,12 @@ def _fabric_events(rec: Recorder) -> List[Dict[str, Any]]:
 
 
 # ---------------------------------------------------------------------------
-# Serving trace: one steps track (span per decode step, duration = the
-# step's sync round trip + the motion its admission performed) and one
-# motion track per expander (park/resume payload spans priced by
-# serve_motion_time on that expander's own DeviceConfig).
-# ---------------------------------------------------------------------------
-
-def _serve_events(rec: Recorder) -> List[Dict[str, Any]]:
-    if not rec.steps and not rec.serve_events:
-        return []
-    from repro.simx import time as TM
-    n_exp = rec.serve_info["n_expanders"] if rec.serve_info else 1
-    devs = TM.resolve_fleet(None, n_exp)
-    sync_us = max(d.cxl_lat for d in devs) * 1e6
-    ev: List[Dict[str, Any]] = [
-        {"ph": "M", "pid": _SERVE_PID, "tid": 0, "name": "process_name",
-         "args": {"name": "serve"}},
-        {"ph": "M", "pid": _SERVE_PID, "tid": 1, "name": "thread_name",
-         "args": {"name": "steps"}}]
-    for e in range(n_exp):
-        ev.append({"ph": "M", "pid": _SERVE_PID, "tid": 10 + e,
-                   "name": "thread_name",
-                   "args": {"name": f"expander{e}/motion"}})
-    by_step: Dict[int, List[Dict[str, Any]]] = {}
-    for s_ev in rec.serve_events:
-        by_step.setdefault(int(s_ev["step"]), []).append(s_ev)
-    cursor = 0.0
-    exp_cursor = [0.0] * n_exp
-    for i in range(len(rec.steps) + 1):
-        start = cursor
-        motion_us = 0.0
-        for s_ev in by_step.get(i, ()):
-            if s_ev["type"] == "admission":
-                ev.append({"ph": "i", "pid": _SERVE_PID, "tid": 1,
-                           "ts": start, "s": "t",
-                           "name": f"admit x{s_ev['n']} "
-                                   f"(bucket {s_ev['bucket']})"})
-                continue
-            e = int(s_ev["expander"]) % n_exp
-            pb = s_ev["bytes"] if s_ev["type"] == "preempt" else 0
-            rb = s_ev["bytes"] if s_ev["type"] == "resume" else 0
-            dur = float(TM.serve_motion_time(float(pb), float(rb),
-                                             devs[e], np)) * 1e6
-            ts = max(exp_cursor[e], start)
-            ev.append({"ph": "X", "pid": _SERVE_PID, "tid": 10 + e,
-                       "ts": ts, "dur": dur, "name": s_ev["type"],
-                       "args": {k: v for k, v in s_ev.items()
-                                if k not in ("type", "step")}})
-            exp_cursor[e] = ts + dur
-            motion_us += dur
-        if i < len(rec.steps):
-            st = rec.steps[i]
-            dur = sync_us + motion_us
-            ev.append({"ph": "X", "pid": _SERVE_PID, "tid": 1,
-                       "ts": start, "dur": dur,
-                       "name": f"step {st['step']}",
-                       "args": {"active": len(st["active"]),
-                                "done": len(st["done"]),
-                                "max_pos": st["max_pos"]}})
-            cursor = start + dur
-    return ev
-
-
-# ---------------------------------------------------------------------------
 # Public API.
 # ---------------------------------------------------------------------------
 
 def build_trace(rec: Recorder) -> Dict[str, Any]:
     """Chrome/Perfetto ``trace_event`` JSON for everything recorded."""
-    events = _fabric_events(rec) + _serve_events(rec)
+    events = _fabric_events(rec)
     other: Dict[str, Any] = {"manifest": manifest()}
     totals = fabric_track_totals(rec)
     if totals is not None:
@@ -342,13 +278,6 @@ def metrics_snapshot(rec: Recorder, **meta: Any) -> Dict[str, Any]:
         out["fabric"] = fab
     if rec.cells:
         out["simx"] = {"cells": rec.cells}
-    if rec.serve_info is not None:
-        out["serve"] = {
-            "lanes": rec.serve_info["lanes"],
-            "n_expanders": rec.serve_info["n_expanders"],
-            "steps": len(rec.steps),
-            "events": len(rec.serve_events),
-        }
     return out
 
 

@@ -1,11 +1,10 @@
 """The Recorder: host-side accumulator for piggybacked telemetry.
 
 Every ``record_*`` method consumes values that are ALREADY host numpy —
-the deltas, times and flags that fell out of the hot paths' single
+the deltas, times and flags that fell out of the fabric's single
 contracted fetches (``Fabric._fetch_view``'s fused per-segment sync,
-``Fabric._commit_epoch``'s per-epoch sync, ``serve.Engine.step``'s one
-``(tok, done, ref, pos)`` fetch) plus host-only scheduling facts
-(migration plans, admissions, park/resume bookkeeping). Handing the
+``Fabric._commit_epoch``'s per-epoch sync) plus host-only scheduling facts
+(migration plans, simx cells). Handing the
 Recorder a device value is a bug the analyzer's R6 rule flags at the
 source level; at runtime the contracts' budgets stay unchanged because
 nothing here ever crosses the host/device boundary.
@@ -14,16 +13,18 @@ Samples land in two places: a :class:`~repro.obs.registry.MetricsRegistry`
 (aggregates; counter metrics keyed by ``state.COUNTER_NAMES`` via zip —
 no integer-literal indexing, the R3 layout rule stays clean) and ordered
 per-domain event lists (``segments`` / ``plans`` / ``epochs`` for the
-fabric, ``steps`` / ``serve_events`` for serving) that the exporters in
-``repro.obs.export`` turn into a Perfetto timeline and ``metrics.json``.
+fabric) that the exporters in ``repro.obs.export`` turn into a Perfetto
+timeline and ``metrics.json``. The serving engine records no samples here:
+its telemetry is its host spans on the profiler's clock
+(``repro.obs.spans``) and ``Engine.counters``.
 
 The module imports neither jax nor the engine packages at module level
 (``repro.obs`` must import on jax-free hosts for ``manifest()``); the
-counter-name table is pulled lazily on first fabric/serve attach.
+counter-name table is pulled lazily on the first fabric sample.
 """
 from __future__ import annotations
 
-from typing import Any, Dict, List, Optional, Sequence
+from typing import Any, Dict, List, Optional
 
 import numpy as np
 
@@ -35,8 +36,8 @@ TIME_US_BOUNDS = tuple(m * 10 ** e for e in range(0, 8) for m in (1, 2, 5))
 
 
 class Recorder:
-    """Accumulates piggybacked samples from one run (one fabric and/or
-    one serving engine). Opt-in: constructed by the caller and passed as
+    """Accumulates piggybacked samples from one run (one fabric, simx
+    cells). Opt-in: constructed by the caller and passed as
     ``obs=`` — the ``obs=None`` default everywhere is the recording-off
     path, bit-identical in pool/counter state (tests/test_obs.py)."""
 
@@ -46,12 +47,8 @@ class Recorder:
         self.segments: List[Dict[str, Any]] = []   # one per replayed segment
         self.plans: List[Dict[str, Any]] = []      # one per non-empty plan
         self.epochs: List[Dict[str, Any]] = []     # one per committed epoch
-        # serving timeline
-        self.steps: List[Dict[str, Any]] = []      # one per decode step
-        self.serve_events: List[Dict[str, Any]] = []   # admissions/motion
         self.cells: List[Dict[str, Any]] = []      # simx workload cells
         self.fabric_info: Optional[Dict[str, Any]] = None
-        self.serve_info: Optional[Dict[str, Any]] = None
 
     # -- attach ---------------------------------------------------------------
 
@@ -80,16 +77,6 @@ class Recorder:
             # exporters derive the block expander->device placement from
             # (n_expanders, shard_devices) — the recorder stays jax-free.
             "shard_devices": getattr(fabric, "shard_devices", None),
-        }
-
-    def attach_serve(self, engine) -> None:
-        """Called by ``serve._EngineBase.__init__`` when constructed with
-        ``obs=``."""
-        self.serve_info = {
-            "lanes": engine.lanes,
-            "n_expanders": engine.n_expanders,
-            "max_len": engine.max_len,
-            "family": engine.cfg.family,
         }
 
     # -- fabric drains (host values from the contracted fetches) --------------
@@ -171,59 +158,3 @@ class Recorder:
         self.metrics.gauge(
             f"simx.normalized_perf.{scheme}.{workload}").set(
             float(metrics["normalized_perf"]))
-
-    # -- serving drains --------------------------------------------------------
-
-    def record_step(self, step: int, toks: np.ndarray, done: np.ndarray,
-                    pos: np.ndarray, active: Sequence[int]) -> None:
-        """One decode step, from ``Engine.step``'s single fetch of the
-        ``(tok, done, ref, pos)`` quad: emitted tokens, completion flags
-        and per-lane positions for the lanes that were active."""
-        active = list(int(a) for a in active)
-        self.steps.append({
-            "step": int(step), "active": active,
-            "done": [int(l) for l in active if bool(np.asarray(done)[l])],
-            "max_pos": int(np.max(np.asarray(pos)[active])) if active else 0,
-        })
-        self.metrics.counter("serve.steps").inc()
-        self.metrics.counter("serve.tokens").inc(len(active))
-        self.metrics.gauge("serve.active_lanes").set(float(len(active)))
-        self.metrics.histogram("serve.active_lanes").observe(len(active))
-
-    def _serve_event(self, kind: str, **fields) -> None:
-        ev = {"type": kind, "step": len(self.steps)}
-        ev.update(fields)
-        self.serve_events.append(ev)
-
-    def record_admission(self, n: int, bucket: int) -> None:
-        """One bucketed prefill batch (host scheduling fact)."""
-        self._serve_event("admission", n=int(n), bucket=int(bucket))
-        self.metrics.counter("serve.admissions").inc(int(n))
-        self.metrics.counter("serve.prefill_batches").inc()
-        self.metrics.histogram("serve.prefill_bucket").observe(int(bucket))
-
-    def record_preempt(self, lane: int, rid: int, nbytes: int, shadow: bool,
-                       expander: int) -> None:
-        """One lane preemption: ``nbytes`` parked (0 when the shadow still
-        covered every token — the §4.5 zero-byte re-preempt)."""
-        self._serve_event("preempt", lane=int(lane), rid=int(rid),
-                          bytes=int(nbytes), shadow=bool(shadow),
-                          expander=int(expander))
-        self.metrics.counter("serve.preemptions").inc()
-        self.metrics.counter("serve.preempt_bytes").inc(int(nbytes))
-        if shadow:
-            self.metrics.counter("serve.shadow_repreempts").inc()
-        self.metrics.histogram("serve.preempt_bytes").observe(int(nbytes))
-
-    def record_resume(self, lane: int, rid: int, nbytes: int,
-                      cross_expander: bool, expander: int) -> None:
-        """One parked-request resume (promotion): compressed payload
-        installed without dequantizing."""
-        self._serve_event("resume", lane=int(lane), rid=int(rid),
-                          bytes=int(nbytes), cross=bool(cross_expander),
-                          expander=int(expander))
-        self.metrics.counter("serve.resumes").inc()
-        self.metrics.counter("serve.resume_bytes").inc(int(nbytes))
-        if cross_expander:
-            self.metrics.counter("serve.cross_expander_resumes").inc()
-        self.metrics.histogram("serve.resume_bytes").observe(int(nbytes))

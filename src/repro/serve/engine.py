@@ -28,11 +28,15 @@ bit, active mask, remaining budget) lives in device arrays and is advanced
 *inside* the jitted engine step — argmax, position advance, done detection
 and reference-bit updates all happen on device. The host performs exactly
 ONE device sync per decode step (``counters["step_syncs"]``): a single
-``device_get`` of the (tokens, done, ref, pos) quad that drives per-request
-Python bookkeeping (and, when a ``repro.obs.Recorder`` is attached, the
-per-step telemetry sample — piggybacked, zero extra syncs). Admission-path
-syncs (one per prefill bucket, one per demotion fetch) are counted
-separately in ``counters["admit_syncs"]``.
+``device_get`` of the (tokens, done, ref) triple that drives per-request
+Python bookkeeping. Admission-path syncs (one per prefill bucket, one per
+demotion fetch) are counted separately in ``counters["admit_syncs"]``.
+
+**Telemetry.** The engine's phases are host spans on the profiler's clock
+(``repro.obs.spans``: step, admit, prefill, preempt, park and its demote
+and fetch, resume, decode, fetch), written only while a profiler runs;
+``counters`` holds the counts, among them the bytes a park really fetches
+and a resume really uploads.
 
 **Prefill batching.** Fresh requests admitted in the same engine step are
 prefilled together, grouped into power-of-two length buckets (right-padded;
@@ -69,6 +73,7 @@ from repro.core.compressor import quantize_blocks_fast
 from repro.core.engine.policy import SecondChanceLanes
 from repro.models import decode as D
 from repro.models import transformer as T
+from repro.obs import spans
 
 WAITING, RUNNING, PREEMPTED, DONE = "waiting", "running", "preempted", "done"
 
@@ -179,14 +184,25 @@ def _demote_lane_impl(lane_cache, pos, *, scfg: ServeConfig):
 @functools.lru_cache(maxsize=32)
 def _compiled_fns(cfg: ModelConfig, scfg: ServeConfig, max_len: int):
     """Engine-shared jitted fns, cached on the hashable configs so
-    constructing N engines (tests, replicas) compiles once."""
-    step = jax.jit(functools.partial(_engine_step_impl, cfg=cfg, scfg=scfg,
-                                     max_len=max_len))
-    pre = jax.jit(functools.partial(_prefill_impl, cfg=cfg, scfg=scfg,
-                                    max_len=max_len))
-    demote = jax.jit(functools.partial(_demote_lane_impl, scfg=scfg))
-    decode = jax.jit(functools.partial(D.decode_step, cfg=cfg, scfg=scfg))
-    return step, pre, demote, decode
+    constructing N engines (tests, replicas) compiles once. Each is a named
+    function, so that its device module is ``jit_serve_<name>`` in a
+    profile (a jitted ``functools.partial`` is ``jit__unknown``)."""
+    def serve_decode_step(params, cache, state, embeds=None):
+        return _engine_step_impl(params, cache, state, embeds, cfg=cfg,
+                                 scfg=scfg, max_len=max_len)
+
+    def serve_prefill(params, batch, lens):
+        return _prefill_impl(params, batch, lens, cfg=cfg, scfg=scfg,
+                             max_len=max_len)
+
+    def serve_demote_lane(lane_cache, pos):
+        return _demote_lane_impl(lane_cache, pos, scfg=scfg)
+
+    def serve_decode(params, cache, tokens, pos, embeds=None):
+        return D.decode_step(params, cache, tokens, pos, cfg, scfg, embeds)
+
+    return (jax.jit(serve_decode_step), jax.jit(serve_prefill),
+            jax.jit(serve_demote_lane), jax.jit(serve_decode))
 
 
 # ---------------------------------------------------------------------------
@@ -242,12 +258,17 @@ def _lanes_install(cache, lanes: jnp.ndarray, sub_cache):
     return out
 
 
+def _leaf_bytes(tree) -> int:
+    return sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(tree))
+
+
 def _moved_bytes(parked: Dict[str, Any], n_tokens: int, max_len: int) -> int:
-    """Bytes a park/restore actually moves: the compressed payload (codes +
-    scales) of ``n_tokens`` tokens, plus raw recurrent state for ssm/hybrid
-    in full (it has no compressed form and no append-only prefix). The
-    counter is the modeled CXL traffic of the motion — the full-length host
-    buffers are an implementation detail."""
+    """Bytes a park/restore moves in the model: the compressed payload
+    (codes + scales) of ``n_tokens`` tokens, plus raw recurrent state for
+    ssm/hybrid in full (it has no compressed form and no append-only
+    prefix). The counter is the modeled CXL traffic of the motion; what the
+    full-length host buffers really copy is ``park_fetch_bytes`` and
+    ``resume_upload_bytes``."""
     total = 0
     for k, v in parked.items():
         if k == "ssm":
@@ -267,7 +288,7 @@ def _moved_bytes(parked: Dict[str, Any], n_tokens: int, max_len: int) -> int:
 
 class _EngineBase:
     def __init__(self, cfg: ModelConfig, scfg: ServeConfig, params,
-                 max_len: int = 2048, seed: int = 0, obs=None):
+                 max_len: int = 2048, seed: int = 0):
         self.cfg, self.scfg = cfg, scfg
         self.params = params
         self.max_len = max_len
@@ -285,7 +306,12 @@ class _EngineBase:
                          "resume_bytes": 0, "steps": 0, "tokens": 0,
                          "step_syncs": 0, "admit_syncs": 0,
                          "shadow_repreempts": 0, "prefill_batches": 0,
-                         "cross_expander_resumes": 0}
+                         "cross_expander_resumes": 0,
+                         # bytes that really cross between host and device:
+                         # every leaf a park fetches, every host array a
+                         # resume uploads (preempt_bytes / resume_bytes
+                         # are the modeled CXL payload)
+                         "park_fetch_bytes": 0, "resume_upload_bytes": 0}
         # fabric-aware serving: lanes stripe across the expander pool fabric;
         # preempted payloads park on (and are charged to) their lane's
         # expander, and victim selection balances parked load across
@@ -303,12 +329,6 @@ class _EngineBase:
         (self._step_fn, self._prefill_fn, self._demote_fn,
          self._decode_fn) = _compiled_fns(
             cfg, dataclasses.replace(scfg, n_expanders=1), max_len)
-        # telemetry (repro.obs.Recorder, DESIGN.md §16): samples ride the
-        # contracted fetches the engine already performs — attaching a
-        # recorder changes neither sync counts nor any device state
-        self.obs = obs
-        if obs is not None:
-            obs.attach_serve(self)
 
     # -- client API ---------------------------------------------------------
 
@@ -375,21 +395,27 @@ class _EngineBase:
         """Demote the lane on device (quantize ring -> codes) and park the
         compressed payload on the lane's expander, charging only the suffix
         not already covered by the request's shadow."""
-        covered = req.shadow_pos if req.parked is not None else 0
-        exp = int(self.lane_expander[lane])
-        if req.parked is None or req.expander != exp:
-            if req.parked is not None and req.expander >= 0:
-                self.expander_stats["parked"][req.expander] -= 1
-            self.expander_stats["parked"][exp] += 1
-        lane_cache = _lane_slice(self.cache, lane)
-        demoted = self._demote_fn(lane_cache, jnp.asarray(req.pos, jnp.int32))
-        kept = {k: v for k, v in demoted.items() if k not in HOT_KEYS}
-        req.parked = self._fetch(kept, "admit_syncs")
-        req.shadow_pos = req.pos
-        req.expander = exp
-        moved = _moved_bytes(req.parked, req.pos - covered, self.max_len)
-        self.counters["preempt_bytes"] += moved
-        self.expander_stats["preempt_bytes"][exp] += moved
+        with spans.span(spans.PARK, rid=req.rid, lane=lane, tokens=req.pos):
+            covered = req.shadow_pos if req.parked is not None else 0
+            exp = int(self.lane_expander[lane])
+            if req.parked is None or req.expander != exp:
+                if req.parked is not None and req.expander >= 0:
+                    self.expander_stats["parked"][req.expander] -= 1
+                self.expander_stats["parked"][exp] += 1
+            with spans.span(spans.PARK_DEMOTE):
+                lane_cache = _lane_slice(self.cache, lane)
+                demoted = self._demote_fn(lane_cache,
+                                          jnp.asarray(req.pos, jnp.int32))
+                kept = {k: v for k, v in demoted.items()
+                        if k not in HOT_KEYS}
+            with spans.span(spans.PARK_FETCH):
+                req.parked = self._fetch(kept, "admit_syncs")
+            self.counters["park_fetch_bytes"] += _leaf_bytes(req.parked)
+            req.shadow_pos = req.pos
+            req.expander = exp
+            moved = _moved_bytes(req.parked, req.pos - covered, self.max_len)
+            self.counters["preempt_bytes"] += moved
+            self.expander_stats["preempt_bytes"][exp] += moved
 
     def _install_parked(self, req: Request, lane: int) -> None:
         """Promotion: install parked codes into the lane (empty ring, full
@@ -399,11 +425,11 @@ class _EngineBase:
         for k, a in self.cache.items():
             if k in HOT_KEYS:
                 lane_tree[k] = jnp.zeros(a.shape[:1] + a.shape[2:], a.dtype)
-            elif k == "ssm":
+            else:
                 lane_tree[k] = jax.tree_util.tree_map(jnp.asarray,
                                                       req.parked[k])
-            else:
-                lane_tree[k] = jnp.asarray(req.parked[k])
+                self.counters["resume_upload_bytes"] += \
+                    _leaf_bytes(req.parked[k])
         self.cache = _lane_install(self.cache, lane, lane_tree)
         moved = _moved_bytes(req.parked, req.pos, self.max_len)
         self.counters["resume_bytes"] += moved
@@ -419,8 +445,6 @@ class _EngineBase:
             self.expander_stats["parked"][exp] += 1
             req.expander = exp
         self.counters["promotions"] += 1
-        if self.obs is not None:
-            self.obs.record_resume(lane, req.rid, moved, cross, exp)
         req.lane = lane
         req.state = RUNNING
         self.lane_req[lane] = req.rid
@@ -430,8 +454,8 @@ class Engine(_EngineBase):
     """Device-resident batched scheduler (module docstring has the design)."""
 
     def __init__(self, cfg: ModelConfig, scfg: ServeConfig, params,
-                 max_len: int = 2048, seed: int = 0, obs=None):
-        super().__init__(cfg, scfg, params, max_len, seed, obs=obs)
+                 max_len: int = 2048, seed: int = 0):
+        super().__init__(cfg, scfg, params, max_len, seed)
         # device-resident lane bookkeeping, advanced inside the jitted step
         self.state = {
             "tok": jnp.zeros((self.lanes,), jnp.int32),
@@ -518,46 +542,45 @@ class Engine(_EngineBase):
             L = self._bucket(len(self.requests[rid].prompt))
             groups.setdefault(L, []).append((rid, lane))
         for L, grp in sorted(groups.items()):
-            k = len(grp)
-            Bp = _next_pow2(k)          # pad rows too: fewer compiled shapes
-            tokens = np.zeros((Bp, L), np.int32)
-            lens = np.ones((Bp,), np.int32)
-            for i, (rid, _) in enumerate(grp):
-                p = self.requests[rid].prompt
-                tokens[i, :len(p)] = p
-                lens[i] = len(p)
-            batch = {"tokens": jnp.asarray(tokens)}
-            if self.cfg.frontend != "none":
-                batch["embeds"] = jnp.zeros((Bp, L, self.cfg.d_model),
-                                            jnp.bfloat16)
-            toks, sub = self._prefill_fn(self.params, batch,
-                                         jnp.asarray(lens))
-            lanes_arr = jnp.asarray([lane for _, lane in grp])
-            ax = _ssm_batch_axis(self.cache)
-            real = {kk: (jax.tree_util.tree_map(
-                        lambda a: jax.lax.slice_in_dim(a, 0, k, axis=ax), vv)
-                        if kk == "ssm" else vv[:, :k])
-                    for kk, vv in sub.items()}
-            self.cache = _lanes_install(self.cache, lanes_arr, real)
-            toks_h = self._fetch(toks[:k], "admit_syncs")
-            self.counters["prefill_batches"] += 1
-            if self.obs is not None:
-                self.obs.record_admission(k, L)
-            for i, (rid, lane) in enumerate(grp):
-                req = self.requests[rid]
-                req.generated.append(int(toks_h[i]))
-                req.pos = int(lens[i])
-                req.lane = lane
-                req.state = RUNNING
-                self.counters["promotions"] += 1
-                remaining = req.max_new_tokens - 1
-                if remaining <= 0 or req.pos >= self.max_len - 1:
-                    req.state = DONE
-                    req.lane = -1
-                    self.lane_req[lane] = None
-                else:
-                    self._set_lane_state(lane, int(toks_h[i]), req.pos,
-                                         remaining)
+            with spans.span(spans.PREFILL, bucket=L, rows=len(grp)):
+                k = len(grp)
+                Bp = _next_pow2(k)      # pad rows too: fewer compiled shapes
+                tokens = np.zeros((Bp, L), np.int32)
+                lens = np.ones((Bp,), np.int32)
+                for i, (rid, _) in enumerate(grp):
+                    p = self.requests[rid].prompt
+                    tokens[i, :len(p)] = p
+                    lens[i] = len(p)
+                batch = {"tokens": jnp.asarray(tokens)}
+                if self.cfg.frontend != "none":
+                    batch["embeds"] = jnp.zeros((Bp, L, self.cfg.d_model),
+                                                jnp.bfloat16)
+                toks, sub = self._prefill_fn(self.params, batch,
+                                             jnp.asarray(lens))
+                lanes_arr = jnp.asarray([lane for _, lane in grp])
+                ax = _ssm_batch_axis(self.cache)
+                real = {kk: (jax.tree_util.tree_map(
+                            lambda a: jax.lax.slice_in_dim(a, 0, k, axis=ax),
+                            vv) if kk == "ssm" else vv[:, :k])
+                        for kk, vv in sub.items()}
+                self.cache = _lanes_install(self.cache, lanes_arr, real)
+                toks_h = self._fetch(toks[:k], "admit_syncs")
+                self.counters["prefill_batches"] += 1
+                for i, (rid, lane) in enumerate(grp):
+                    req = self.requests[rid]
+                    req.generated.append(int(toks_h[i]))
+                    req.pos = int(lens[i])
+                    req.lane = lane
+                    req.state = RUNNING
+                    self.counters["promotions"] += 1
+                    remaining = req.max_new_tokens - 1
+                    if remaining <= 0 or req.pos >= self.max_len - 1:
+                        req.state = DONE
+                        req.lane = -1
+                        self.lane_req[lane] = None
+                    else:
+                        self._set_lane_state(lane, int(toks_h[i]), req.pos,
+                                             remaining)
 
     def _preempt(self, lane: int) -> None:
         """Demote the lane. A shadow still covering every token short-
@@ -570,17 +593,11 @@ class Engine(_EngineBase):
         resume and decode; the organic payoff is the suffix-only charge."""
         rid = self.lane_req[lane]
         req = self.requests[rid]
-        shadow_hit = req.parked is not None and req.shadow_pos >= req.pos
-        if shadow_hit:
-            self.counters["shadow_repreempts"] += 1
-            moved = 0
-        else:
-            before = self.counters["preempt_bytes"]
-            self._park_lane(req, lane)
-            moved = self.counters["preempt_bytes"] - before
-        if self.obs is not None:
-            self.obs.record_preempt(lane, rid, moved, shadow_hit,
-                                    int(self.lane_expander[lane]))
+        with spans.span(spans.PREEMPT, rid=rid, lane=lane):
+            if req.parked is not None and req.shadow_pos >= req.pos:
+                self.counters["shadow_repreempts"] += 1
+            else:
+                self._park_lane(req, lane)
         self.counters["demotions"] += 1
         req.state = PREEMPTED
         req.lane = -1
@@ -591,9 +608,10 @@ class Engine(_EngineBase):
     def _resume(self, req: Request, lane: int) -> None:
         """Promotion; the parked copy stays behind as a shadow — its prefix
         (append-only KV) stays valid no matter how many tokens follow."""
-        self._install_parked(req, lane)
-        self._set_lane_state(lane, req.generated[-1], req.pos,
-                             req.max_new_tokens - len(req.generated))
+        with spans.span(spans.RESUME, rid=req.rid, lane=lane):
+            self._install_parked(req, lane)
+            self._set_lane_state(lane, req.generated[-1], req.pos,
+                                 req.max_new_tokens - len(req.generated))
 
     # -- decode step ---------------------------------------------------------
 
@@ -603,36 +621,35 @@ class Engine(_EngineBase):
         Exactly one host sync per call once lanes are running — declared
         above and checked both by the R5 lint and by the benches via
         ``verify_sync_counters`` (step_syncs == steps)."""
-        self._admit()
-        active = [(lane, rid) for lane, rid in enumerate(self.lane_req)
-                  if rid is not None]
-        if not active:
-            return bool(self.queue)
-        kwargs = {}
-        if self.cfg.frontend != "none":
-            kwargs["embeds"] = jnp.zeros((self.lanes, self.cfg.d_model),
-                                         jnp.bfloat16)
-        self.cache, self.state, done = self._step_fn(
-            self.params, self.cache, self.state, **kwargs)
-        self.counters["steps"] += 1
-        # ONE fused fetch: the lane positions ride along unconditionally —
-        # a conditional fetch would be a second lexical sync site (R5) —
-        # and feed the telemetry drain below at zero extra syncs
-        tok_h, done_h, ref_h, pos_h = self._fetch(
-            (self.state["tok"], done, self.state["ref"], self.state["pos"]),
-            "step_syncs")
-        self._ref = np.array(ref_h, bool, copy=True)
-        if self.obs is not None:
-            self.obs.record_step(self.counters["steps"], tok_h, done_h,
-                                 pos_h, [lane for lane, _ in active])
-        for lane, rid in active:
-            req = self.requests[rid]
-            req.pos += 1
-            req.generated.append(int(tok_h[lane]))
-            self.counters["tokens"] += 1
-            if done_h[lane]:
-                req.state = DONE
-                req.lane = -1
-                self._drop_park(req)       # free the shadow's host memory
-                self.lane_req[lane] = None
+        with spans.span(spans.STEP, step=self.counters["steps"]):
+            with spans.span(spans.ADMIT):
+                self._admit()
+            active = [(lane, rid) for lane, rid in enumerate(self.lane_req)
+                      if rid is not None]
+            if not active:
+                return bool(self.queue)
+            kwargs = {}
+            if self.cfg.frontend != "none":
+                kwargs["embeds"] = jnp.zeros((self.lanes, self.cfg.d_model),
+                                             jnp.bfloat16)
+            with spans.span(spans.DECODE):
+                self.cache, self.state, done = self._step_fn(
+                    self.params, self.cache, self.state, **kwargs)
+            self.counters["steps"] += 1
+            # ONE fused fetch
+            with spans.span(spans.FETCH):
+                tok_h, done_h, ref_h = self._fetch(
+                    (self.state["tok"], done, self.state["ref"]),
+                    "step_syncs")
+            self._ref = np.array(ref_h, bool, copy=True)
+            for lane, rid in active:
+                req = self.requests[rid]
+                req.pos += 1
+                req.generated.append(int(tok_h[lane]))
+                self.counters["tokens"] += 1
+                if done_h[lane]:
+                    req.state = DONE
+                    req.lane = -1
+                    self._drop_park(req)   # free the shadow's host memory
+                    self.lane_req[lane] = None
         return True

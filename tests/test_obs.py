@@ -4,9 +4,9 @@
     Recorder only consumes host values the contracted fetches already
     produced, so attaching it cannot perturb the run;
   * the declared sync budgets hold with the Recorder attached:
-    ``segment_syncs == segments``, ``epoch_syncs == epochs`` (fabric) and
-    ``step_syncs == steps`` (serve) — zero extra syncs, asserted against
-    the ``@sync_contract`` declarations, not bench constants;
+    ``segment_syncs == segments``, ``epoch_syncs == epochs`` — zero extra
+    syncs, asserted against the ``@sync_contract`` declarations, not bench
+    constants (the serve engine's spans: tests/test_spans.py);
   * the Perfetto export validates (spans nest, timestamps monotone per
     track) and its per-expander track totals reconcile with
     ``Fabric.pipeline_times()`` — the trace is the same accounting, drawn;
@@ -16,30 +16,21 @@
 """
 import json
 
-import jax
 import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.common.contracts import verify_sync_counters
-from repro.common.types import ServeConfig
-from repro.configs import get_reduced
 from repro.core.engine.policy import POLICIES
 from repro.fabric import Fabric, WeightedInterleave
-from repro.models import transformer as T
 from repro.obs import Recorder, manifest
 from repro.obs import export as OBX
 from repro.obs.registry import Histogram, MetricsRegistry, merge_histograms
-from repro.serve.engine import Engine
 from repro.simx.engine import pool_cfg_for
 from repro.simx.trace import WORKLOADS, make_rates_table, make_trace
 
 POLICY = POLICIES["ibex"]
 WINDOW = 8
-
-CFG = get_reduced("llama3_8b")
-SCFG = ServeConfig(max_running=2, hot_window=16, attn_chunk=32,
-                   kv_rate_bits=8)
 
 
 # -- shared fixtures ---------------------------------------------------------
@@ -74,16 +65,6 @@ def recorded_fabric():
     fab = _rebalance_fabric(cfg, rates, obs=rec)
     fab.replay(ospn, wr, blk)
     return cfg, rates, (ospn, wr, blk), rec, fab
-
-
-@pytest.fixture(scope="module")
-def params():
-    return T.init_params(jax.random.PRNGKey(0), CFG)[0]
-
-
-def _prompt(seed, n=20):
-    return list(np.random.default_rng(seed).integers(
-        1, CFG.vocab_size, size=n))
 
 
 # -- fabric: bit-identity + sync budgets -------------------------------------
@@ -175,40 +156,6 @@ def test_trace_validator_rejects_malformed():
         {"ph": "B", "pid": 1, "tid": 0, "ts": 0.0, "name": "p"},
     ])
     assert OBX.validate_trace(bad_phase)
-
-
-# -- serve: bit-identity + sync budget ----------------------------------------
-
-def test_serve_recording_identical_and_one_sync_per_step(params):
-    """The batched engine with a Recorder attached finishes with counters
-    identical to the recording-off run, still syncing exactly once per
-    decode step; the Recorder saw every step and the motion events."""
-    def run(obs=None):
-        eng = Engine(CFG, SCFG, params, max_len=128, obs=obs)
-        rids = [eng.submit(_prompt(i), max_new_tokens=6) for i in range(4)]
-        eng.run_until_done(max_steps=400)
-        return eng, [eng.result(r) for r in rids]
-
-    rec = Recorder()
-    eng_on, out_on = run(obs=rec)
-    eng_off, out_off = run()
-    assert eng_on.counters == eng_off.counters, \
-        "recording changed the engine's counters"
-    assert out_on == out_off, "recording changed decoded tokens"
-    assert eng_on.counters["step_syncs"] == eng_on.counters["steps"]
-    verify_sync_counters(Engine.step, eng_on.counters["steps"],
-                         eng_on.counters["step_syncs"],
-                         what="recorder attached")
-    assert len(rec.steps) == eng_on.counters["steps"]
-    kinds = {ev["type"] for ev in rec.serve_events}
-    assert "admission" in kinds
-    # 4 requests through 2 lanes must have parked someone
-    assert "preempt" in kinds and "resume" in kinds
-    snap = rec.metrics.snapshot()["counters"]
-    assert snap["serve.preempt_bytes"] == eng_on.counters["preempt_bytes"]
-    assert snap["serve.resume_bytes"] == eng_on.counters["resume_bytes"]
-    trace = OBX.build_trace(rec)
-    assert OBX.validate_trace(trace) == []
 
 
 # -- registry ------------------------------------------------------------------
