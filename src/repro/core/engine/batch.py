@@ -137,9 +137,10 @@ def _window_step(pool: Pool, cfg: PoolConfig, policy: Policy, xs,
     # raised target (watermark + expected promotions per window) so the
     # free list rarely exhausts mid-window; a window with more promotions
     # than that stays live through the promote path's self-ensure.
-    # fori-of-cond, not while: XLA executes a skipped cond branch as a
-    # cheap copy, whereas demotions inside a dynamic-trip while loop cost
-    # ~3x (measured on CPU).
+    # The top-up is a while loop that stops once the target is met, each
+    # demotion a write-set transition (ops.py), so it copies no pool-sized
+    # state: on a v5e the former fori-of-cond copied `meta` and the free
+    # lists whole on every iteration, taken or skipped.
     # the raise is bounded by the watermark so small pools keep (almost)
     # the serial engine's residency: a higher target would evict hot pages
     # the serial engine keeps resident and skew traffic at small scales.
@@ -194,11 +195,13 @@ def _window_step(pool: Pool, cfg: PoolConfig, policy: Policy, xs,
 
     # phase 3: serialized replay of the slow accesses only — fast accesses
     # pay no per-access control flow at all. The first SLOW_FORI slow
-    # accesses run in a fori-of-cond (a skipped cond is a cheap copy, and a
-    # taken branch executes at serial-engine cost); the rare overflow (a
-    # window with more slow accesses than SLOW_FORI, e.g. first-touch
-    # population) drains through a while loop, whose heavy bodies XLA runs
-    # ~3x slower — hence the split.
+    # accesses run in a fori whose slot k is masked by k < n_slow (a masked
+    # slot commits a write list that writes back what it reads); the rare
+    # overflow (a window with more slow accesses than SLOW_FORI, e.g.
+    # first-touch population) drains through a while loop. A slot is a
+    # write-set transition (ops.py): the v5e trace showed the former
+    # per-slot cond copying the pool's `meta` and free lists whole, even
+    # for a skipped slot, and those copies were most of the drain's time.
     #
     # ``unroll_slow`` replaces BOTH lax loops with a statically unrolled
     # python loop over the full window: XLA:CPU deterministically
@@ -225,37 +228,22 @@ def _drain_slow(pool: Pool, cfg: PoolConfig, policy: Policy, xs, fast,
     slow_order = jnp.argsort(jnp.where(fast, window + jnp.arange(window),
                                        jnp.arange(window)))
 
-    def process(k, p: Pool) -> Pool:
+    def process(k, p: Pool, on) -> Pool:
         if per_access:
-            p = ops.demote_if_needed(p, cfg, policy)
-
-        def do_write(r: Pool) -> Pool:
-            c = policy.on_host_access(bump(r.counters, C_HOST_WR), True)
-            r = r._replace(counters=c)
-            return ops.write_block_op(r, cfg, policy, ospns[k], blocks[k],
-                                      zero_block)
-
-        def do_read(r: Pool) -> Pool:
-            c = policy.on_host_access(bump(r.counters, C_HOST_RD), False)
-            r = r._replace(counters=c)
-            return ops.read_block_op(r, cfg, policy, ospns[k], blocks[k])[0]
-
-        return jax.lax.cond(writes[k], do_write, do_read, p)
+            p = ops.demote_if_needed(p, cfg, policy, on=on)
+        p = p._replace(counters=ops.host_count(p.counters, policy, writes[k],
+                                               on))
+        return ops.access(p, cfg, policy, ospns[k], blocks[k], writes[k],
+                          zero_block, on)[0]
 
     if unroll_slow:
         for i in range(window):
-            pool = jax.lax.cond(i < n_slow,
-                                functools.partial(process, slow_order[i]),
-                                lambda q: q, pool)
+            pool = process(slow_order[i], pool, i < n_slow)
         return pool
 
     k_fori = min(SLOW_FORI, window)
     pool = jax.lax.fori_loop(
-        0, k_fori,
-        lambda i, p: jax.lax.cond(i < n_slow,
-                                  lambda q: process(slow_order[i], q),
-                                  lambda q: q, p),
-        pool)
+        0, k_fori, lambda i, p: process(slow_order[i], p, i < n_slow), pool)
 
     def slow_cond(carry):
         i, _ = carry
@@ -263,7 +251,7 @@ def _drain_slow(pool: Pool, cfg: PoolConfig, policy: Policy, xs, fast,
 
     def slow_body(carry):
         i, p = carry
-        return i + 1, process(slow_order[i], p)
+        return i + 1, process(slow_order[i], p, True)
 
     _, pool = jax.lax.while_loop(slow_cond, slow_body,
                                  (jnp.asarray(k_fori, jnp.int32), pool))
@@ -280,21 +268,16 @@ def _replay_windows(pool: Pool, cfg: PoolConfig, policy: Policy, ospns,
     return pool
 
 
-def _serial_access(pool: Pool, cfg: PoolConfig, policy: Policy, ospn, w, blk
-                   ) -> Pool:
+def _serial_access(pool: Pool, cfg: PoolConfig, policy: Policy, ospn, w, blk,
+                   on=True) -> Pool:
     """One access through the serial per-access path (full prologue — the
     exact body `_replay_serial` scans and the masked window path's partial
     windows replay; sharing it is what makes the fabric's padded replay
-    counter-exact against `replay_trace`)."""
+    counter-exact against `replay_trace`). An access that is not ``on`` is
+    an exact no-op."""
     zero_block = jnp.zeros((cfg.vals_per_block,), jnp.bfloat16)
-
-    def do_write(q):
-        return ops._host_write_block(q, cfg, policy, ospn, blk, zero_block)
-
-    def do_read(q):
-        return ops._host_read_block(q, cfg, policy, ospn, blk)[0]
-
-    return jax.lax.cond(w, do_write, do_read, pool)
+    pool = ops._prologue(pool, cfg, policy, ospn, w, on)
+    return ops.access(pool, cfg, policy, ospn, blk, w, zero_block, on)[0]
 
 
 @functools.partial(jax.jit, static_argnums=(1, 2))
@@ -303,22 +286,14 @@ def _replay_serial(pool: Pool, cfg: PoolConfig, policy: Policy, ospns,
     """The seed's one-access-per-step scan (kept as the batched path's
     reference and for BENCH_simx.json before/after measurements).
 
-    ``valid=None`` processes every access and traces the seed's plain
-    two-way cond — the reference/baseline path must not pay for masking. A
-    bool mask adds an outer cond that makes masked-out accesses exact no-ops
-    (pool and counters untouched) — the batched path pads its trace tail
-    with them so every tail compiles at one shape."""
+    ``valid=None`` processes every access. A bool mask makes masked-out
+    accesses exact no-ops (pool and counters untouched) — the batched path
+    pads its trace tail with them so every tail compiles at one shape."""
     if valid is None:
-        def step(p, x):
-            return _serial_access(p, cfg, policy, *x), None
-        pool, _ = jax.lax.scan(step, pool, (ospns, writes, blocks))
-        return pool
+        valid = jnp.ones(ospns.shape, bool)
 
     def step(p, x):
-        ospn, w, blk, v = x
-        return jax.lax.cond(
-            v, lambda q: _serial_access(q, cfg, policy, ospn, w, blk),
-            lambda q: q, p), None
+        return _serial_access(p, cfg, policy, *x), None
 
     pool, _ = jax.lax.scan(step, pool, (ospns, writes, blocks, valid))
     return pool
@@ -369,11 +344,7 @@ def _replay_windows_masked(pool: Pool, cfg: PoolConfig, policy: Policy,
 
         def part_valid(q: Pool) -> Pool:
             def step(q2, x):
-                ospn, wr, blk, vv = x
-                return jax.lax.cond(
-                    vv, lambda r: _serial_access(r, cfg, policy, ospn, wr,
-                                                 blk),
-                    lambda r: r, q2), None
+                return _serial_access(q2, cfg, policy, *x), None
             q, _ = jax.lax.scan(step, q, (o, w, b, v))
             return q
 

@@ -94,11 +94,12 @@ class Policy:
     # -- residency decisions ------------------------------------------------
 
     def select_victim(self, activity: jnp.ndarray, hand: jnp.ndarray, cache,
-                      rng: jnp.ndarray, force=False) -> act.ScanResult:
+                      rng: jnp.ndarray, force=False) -> act.Victim:
         """Victim selection: the §4.4 second-chance clock over the activity
-        region. (The serving engine applies the same policy shape at lane
-        granularity — see ``SecondChanceLanes``.)"""
-        return act.clock_scan(activity, hand, cache, rng, force=force)
+        region, which it only reads; the engine applies the scan's clears
+        (``activity.clear_scanned``). (The serving engine applies the same
+        policy shape at lane granularity — see ``SecondChanceLanes``.)"""
+        return act.find_victim(activity, hand, cache, rng, force=force)
 
 
 @dataclass(frozen=True)

@@ -50,31 +50,47 @@ def pop(fl: FreeList) -> Tuple[FreeList, jnp.ndarray]:
 
 def push(fl: FreeList, idx: jnp.ndarray) -> FreeList:
     """Push one index; idx < 0 is a no-op (makes masked pushes trivial)."""
-    do = idx >= 0
-    pos = jnp.clip(fl.top, 0, fl.capacity - 1)
-    items = jax.lax.select(do, fl.items.at[pos].set(idx.astype(jnp.int32)), fl.items)
-    top = jnp.where(do, fl.top + 1, fl.top)
-    return FreeList(items, top)
+    return push_n(fl, jnp.asarray(idx)[None])
 
 
 def pop_n(fl: FreeList, k: int, valid_n: jnp.ndarray) -> Tuple[FreeList, jnp.ndarray]:
     """Pop up to ``k`` (static) indices, of which only the first ``valid_n``
-    (dynamic) are actually consumed. Returns int32[k] with -1 padding."""
-    def body(i, carry):
-        fl_c, out = carry
-        take = i < valid_n
-        fl2, idx = pop(fl_c)
-        fl_c = jax.tree_util.tree_map(
-            lambda a, b: jax.lax.select(take, a, b), fl2, fl_c)
-        out = out.at[i].set(jnp.where(take, idx, -1))
-        return fl_c, out
-    out0 = jnp.full((k,), -1, jnp.int32)
-    fl, out = jax.lax.fori_loop(0, k, body, (fl, out0))
-    return fl, out
+    (dynamic) are actually consumed. Returns int32[k] with -1 padding.
+    Pops write nothing: the items are read, the head register moves."""
+    i = jnp.arange(k, dtype=jnp.int32)
+    take = (i < valid_n) & (i < fl.top)
+    vals = fl.items[jnp.clip(fl.top - 1 - i, 0, fl.capacity - 1)]
+    out = jnp.where(take, vals, -1).astype(jnp.int32)
+    return FreeList(fl.items, fl.top - jnp.sum(take).astype(jnp.int32)), out
+
+
+def pack(idxs: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """The non-negative entries of ``idxs`` moved to the front, in order,
+    and their count: the items a ``push_n`` of ``idxs`` pushes."""
+    do = idxs >= 0
+    rank = jnp.where(do, jnp.cumsum(do) - 1, idxs.shape[0])
+    packed = jnp.full(idxs.shape, -1, jnp.int32).at[rank].set(
+        idxs.astype(jnp.int32), mode="drop")
+    return packed, jnp.sum(do).astype(jnp.int32)
+
+
+def write_run(items: jnp.ndarray, at, vals: jnp.ndarray, n) -> jnp.ndarray:
+    """``items`` with ``vals[:n]`` written at ``at, at + 1, ...``: one
+    predicated window write of ``len(vals)`` slots. Exact while the run
+    fits below the capacity, which holds for a push of free chunks (every
+    chunk is free at most once, so a list never holds more than its
+    capacity)."""
+    k = vals.shape[0]
+    cap = items.shape[0]
+    start = jnp.clip(at, 0, cap - k)
+    old = jax.lax.dynamic_slice(items, (start,), (k,))
+    off = start + jnp.arange(k, dtype=jnp.int32) - at
+    mine = (off >= 0) & (off < n)
+    new = jnp.where(mine, vals[jnp.clip(off, 0, k - 1)], old)
+    return jax.lax.dynamic_update_slice(items, new, (start,))
 
 
 def push_n(fl: FreeList, idxs: jnp.ndarray) -> FreeList:
     """Push all non-negative entries of ``idxs`` (static length)."""
-    def body(i, fl_c):
-        return push(fl_c, idxs[i])
-    return jax.lax.fori_loop(0, idxs.shape[0], body, fl)
+    vals, n = pack(idxs)
+    return FreeList(write_run(fl.items, fl.top, vals, n), fl.top + n)

@@ -104,15 +104,16 @@ def migrate_dst(d: Pool, cfg: PoolConfig, policy: Policy, ospn, entry,
     travelled metadata word with the pointers rewritten for the
     destination's allocation."""
     moved_units = (nchunks * (cfg.chunk_bytes // 64)).astype(CTR_DTYPE)
-    d, ptrs, is_group = ops.alloc_chunks(d, cfg, nchunks)
-    d = ops._scatter_page_buf(d, cfg, buf, ptrs, nchunks, is_group)
+    t, ptrs, is_group = ops._alloc(d, ops.begin(d, cfg, "meta"), nchunks)
+    t = ops._scatter_page_buf(t, cfg, buf, ptrs, nchunks, is_group)
     new_entry = entry
     for i in range(7):
         new_entry = md.set_ptr(new_entry, i, jnp.maximum(ptrs[i], 0))
     dc = policy.charge_migration(d.counters, C_DEMO_WR, moved_units)
     dc = bump(dc, C_META_WR, ops.meta_width(cfg, ospn))
     dc = policy.on_compress_store(dc)
-    return d._replace(meta=d.meta.at[ospn].set(new_entry), counters=dc)
+    return ops.commit(d, ops._set(t._replace(counters=dc), "meta", ospn,
+                                  new_entry))
 
 
 def migrate_page(src: Pool, dst: Pool, cfg: PoolConfig, policy: Policy,
