@@ -23,16 +23,36 @@ class MoEConfig:
     dense_d_ff: int = 0                # width of the parallel dense residual MLP
     router_jitter: float = 0.0
     load_balance_coef: float = 0.01
+    # DeepSeek-V2: shared experts every token passes through, as one SwiGLU
+    # of width num_shared_experts * expert_d_ff beside the routed ones
+    num_shared_experts: int = 0
+    norm_topk_prob: bool = True        # renormalise the top-k weights to 1
+    routed_scaling_factor: float = 1.0
+
+
+@dataclass(frozen=True)
+class YaRNConfig:
+    """YaRN RoPE scaling (DeepSeek-V2 ``rope_scaling`` of type "yarn"):
+    frequencies blended between extrapolation and interpolation by a ramp
+    over the rotary dims, and the attention scale times ``mscale**2``."""
+    factor: float = 40.0
+    original_max_position_embeddings: int = 4096
+    beta_fast: float = 32.0
+    beta_slow: float = 1.0
+    mscale: float = 0.707
+    mscale_all_dim: float = 0.707
 
 
 @dataclass(frozen=True)
 class MLAConfig:
-    """Multi-head latent attention (MiniCPM3 / DeepSeek-V2 style)."""
+    """Multi-head latent attention (MiniCPM3 / DeepSeek-V2 style).
+    ``q_lora_rank`` 0: the query is one full projection (no q-LoRA)."""
     q_lora_rank: int = 768
     kv_lora_rank: int = 256
     qk_nope_head_dim: int = 64
     qk_rope_head_dim: int = 32
     v_head_dim: int = 64
+    yarn: Optional[YaRNConfig] = None  # RoPE of the rope dims (None: plain)
 
 
 @dataclass(frozen=True)
@@ -64,6 +84,9 @@ class ModelConfig:
     tie_embeddings: bool = False
     attn_kind: str = "gqa"             # gqa | mla | none
     moe: Optional[MoEConfig] = None
+    # moe family: the first first_k_dense layers carry a dense MLP of width
+    # d_ff in place of the experts (DeepSeek-V2 first_k_dense_replace)
+    first_k_dense: int = 0
     mla: Optional[MLAConfig] = None
     ssm: Optional[SSMConfig] = None
     # hybrid layout: every `attn_period` layers, an attention block is applied,
@@ -110,8 +133,9 @@ class ModelConfig:
         if self.attn_kind == "mla" and self.mla is not None:
             m = self.mla
             qk_dim = m.qk_nope_head_dim + m.qk_rope_head_dim
-            attn = (d * m.q_lora_rank + m.q_lora_rank * self.num_heads * qk_dim
-                    + d * (m.kv_lora_rank + m.qk_rope_head_dim)
+            q = (d * m.q_lora_rank + m.q_lora_rank * self.num_heads * qk_dim
+                 if m.q_lora_rank else d * self.num_heads * qk_dim)
+            attn = (q + d * (m.kv_lora_rank + m.qk_rope_head_dim)
                     + m.kv_lora_rank * self.num_heads * (m.qk_nope_head_dim + m.v_head_dim)
                     + self.num_heads * m.v_head_dim * d)
         else:
@@ -120,7 +144,8 @@ class ModelConfig:
         # mlp
         if self.family in ("moe",) and self.moe is not None:
             mo = self.moe
-            mlp = mo.num_experts * 3 * d * mo.expert_d_ff + d * mo.num_experts
+            mlp = (mo.num_experts + mo.num_shared_experts) * 3 * d * \
+                mo.expert_d_ff + d * mo.num_experts
             if mo.dense_residual:
                 mlp += 3 * d * mo.dense_d_ff
         else:
@@ -135,7 +160,8 @@ class ModelConfig:
             n_attn_uses = L // max(self.attn_period, 1) if self.attn_period else 0
             n += L * ssm_layer + min(self.attn_shared_blocks, max(n_attn_uses, 1)) * (attn + mlp)
             return n
-        n += L * (attn + mlp)
+        k = self.first_k_dense
+        n += L * attn + (L - k) * mlp + k * 3 * d * self.d_ff
         return n
 
     def active_param_count(self) -> int:
@@ -143,7 +169,7 @@ class ModelConfig:
         if self.family != "moe" or self.moe is None:
             return self.param_count()
         mo = self.moe
-        d, L = self.d_model, self.num_layers
+        d, L = self.d_model, self.num_layers - self.first_k_dense
         total = self.param_count()
         all_experts = L * mo.num_experts * 3 * d * mo.expert_d_ff
         active_experts = L * mo.top_k * 3 * d * mo.expert_d_ff
@@ -309,6 +335,9 @@ class ServeConfig:
     hot_window: int = 256              # uncompressed recent-token window (the
                                        # promoted region of the KV pool)
     attn_chunk: int = 2048             # kv chunk for the decode attention scan
+    # most prompt tokens (rows x bucket) in one prefill call; 0: a bucket's
+    # whole admission group is one call
+    prefill_budget: int = 0
     fused_dequant_attention: bool = True  # False = paper-faithful promote-then-read
     # fabric-aware serving: lanes are striped across this many expanders;
     # preempted payloads park per-expander and victim selection balances

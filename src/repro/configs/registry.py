@@ -12,7 +12,7 @@ from repro.common.types import ModelConfig
 ARCH_IDS: List[str] = [
     "chameleon_34b", "qwen3_moe_235b_a22b", "arctic_480b", "deepseek_7b",
     "minicpm3_4b", "codeqwen15_7b", "llama3_8b", "zamba2_2p7b",
-    "musicgen_medium", "falcon_mamba_7b",
+    "musicgen_medium", "falcon_mamba_7b", "deepseek_v2_lite",
 ]
 
 # dashes and dots tolerated on the CLI
@@ -27,6 +27,7 @@ ALIASES: Dict[str, str] = {
     "zamba2-2.7b": "zamba2_2p7b",
     "musicgen-medium": "musicgen_medium",
     "falcon-mamba-7b": "falcon_mamba_7b",
+    "deepseek-v2-lite": "deepseek_v2_lite",
 }
 
 

@@ -12,9 +12,12 @@ The KV cache *is* an IBEX pool specialized for append-only data:
     ever happens; the paper measures 62% clean on general traffic, KV reaches
     100%).
 
-Two read paths for the compressed prefix (EXPERIMENTS.md §Perf):
-  * fused  — dequantize-inside-attention (ops.kvc kernel on TPU; the chunked
-    jnp equivalent under GSPMD): HBM bytes = compressed bytes.  [beyond-paper]
+Two read paths for the compressed prefix:
+  * fused  — dequantize-inside-attention in chunked jnp (the Pallas kvc
+    kernel, ``kernels/kvc_attn.py``, is not on this path): HBM bytes =
+    compressed bytes where XLA fuses the dequantization into the dot
+    operands; at MLA's widths on a v5e it writes each dequantized chunk
+    out first.                                                  [beyond-paper]
   * paper  — promote-then-read: the prefix is materialized to bf16 (an
     optimization_barrier'd buffer = the promoted-region write+read), then
     attended uncompressed.                                       [faithful]
@@ -30,8 +33,10 @@ import jax.numpy as jnp
 from repro.common.types import MLAConfig, ModelConfig, ServeConfig, SSMConfig
 from repro.core.compressor import dequantize_blocks, quantize_blocks
 from repro.models import layers as L
+from repro.models import moe as MOE
 from repro.models import ssm as SSM
 from repro.models import transformer as T
+from repro.obs import spans
 
 Params = Dict[str, Any]
 
@@ -81,8 +86,9 @@ def quantized_attention_partial(q: jnp.ndarray, k_codes, k_scales, v_codes,
 
     fused: chunk-*parallel* flash-decode — every KV chunk computes a local
     softmax partial, then partials merge with a max/sum reduction. Two
-    properties matter: (1) XLA fuses the int4/8 dequant into the dot-operand
-    read, so HBM bytes = compressed bytes [beyond-paper]; (2) the chunk axis
+    properties matter: (1) where XLA fuses the int4/8 dequant into the
+    dot-operand read, HBM bytes = compressed bytes [beyond-paper] (see the
+    module docstring for where it does not); (2) the chunk axis
     is born from a reshape of the sequence axis, so a sequence-sharded cache
     (long_500k cells) turns the merge reductions into small cross-device
     all-reduces — sequence-parallel decode attention for free under GSPMD.
@@ -282,15 +288,23 @@ def _evict_to_codes(codes, scales, hot, pos: jnp.ndarray, cold_len: jnp.ndarray,
     return new_codes, new_scales
 
 
+def serve_mlp(params: Params, lp: Params, i, h: jnp.ndarray,
+              cfg: ModelConfig) -> jnp.ndarray:
+    """Layer ``i``'s MLP on the serve path (prefill and decode): the dense
+    SwiGLU, or the expert layer that drops no token."""
+    return T.layer_mlp(params, lp, i, h, cfg, L.mlp_apply,
+                       lambda p, h, j: MOE.moe_apply_dropless(p, h, cfg, j))
+
+
 # ---------------------------------------------------------------------------
 # Per-layer decode: GQA
 # ---------------------------------------------------------------------------
 
 def gqa_decode_layer(lp: Params, x: jnp.ndarray, cache_l: Dict[str, jnp.ndarray],
-                     pos: jnp.ndarray, cfg: ModelConfig, scfg: ServeConfig
-                     ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+                     pos: jnp.ndarray, cfg: ModelConfig, scfg: ServeConfig,
+                     mlp) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
     """x [B,1,d]; pos [B] current token positions; cache_l holds this layer's
-    slices (no leading layer axis)."""
+    slices (no leading layer axis); ``mlp(h)`` is the layer's MLP."""
     B = x.shape[0]
     W = scfg.hot_window
     bits = scfg.kv_rate_bits
@@ -322,12 +336,7 @@ def gqa_decode_layer(lp: Params, x: jnp.ndarray, cache_l: Dict[str, jnp.ndarray]
     x = x + L.gqa_output(lp["attn"], o, cfg)
 
     h2 = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
-    if cfg.family == "moe":
-        from repro.models.moe import moe_apply
-        y, _ = moe_apply(lp["mlp"], h2, cfg)
-    else:
-        y = L.mlp_apply(lp["mlp"], h2)
-    x = x + y
+    x = x + mlp(h2)
     new_cache = dict(cache_l, k_codes=kc, k_scales=ks, v_codes=vc,
                      v_scales=vs, k_hot=k_hot, v_hot=v_hot, cold_len=new_cold)
     return x, new_cache
@@ -338,13 +347,16 @@ def gqa_decode_layer(lp: Params, x: jnp.ndarray, cache_l: Dict[str, jnp.ndarray]
 # ---------------------------------------------------------------------------
 
 def mla_decode_layer(lp: Params, x: jnp.ndarray, cache_l: Dict[str, jnp.ndarray],
-                     pos: jnp.ndarray, cfg: ModelConfig, scfg: ServeConfig
-                     ) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+                     pos: jnp.ndarray, cfg: ModelConfig, scfg: ServeConfig,
+                     mlp) -> Tuple[jnp.ndarray, Dict[str, jnp.ndarray]]:
+    """MLA layer of one decode step: the new token's latent enters the hot
+    ring, the token leaving the ring is compressed, and the query attends
+    in the latent space (W_UK absorbed into the query, W_UV applied to the
+    attended latent) over the ring and the 4-bit latent codes."""
     m = cfg.mla or MLAConfig()
     B = x.shape[0]
     W = scfg.hot_window
     bits = scfg.kv_rate_bits
-    R = m.kv_lora_rank + m.qk_rope_head_dim
     hD = cfg.num_heads
     h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
     lat_new = L.mla_latent(lp["attn"], h, pos[:, None], cfg)[:, 0]  # [B,R]
@@ -356,36 +368,33 @@ def mla_decode_layer(lp: Params, x: jnp.ndarray, cache_l: Dict[str, jnp.ndarray]
 
     # absorbed query: q_lat [B,H,R_c], q_rope [B,H,rope]
     p = lp["attn"]
-    qx = L.rms_norm(h @ p["wq_a"].astype(h.dtype), p["q_norm"], cfg.norm_eps)
-    q = (qx @ p["wq_b"].astype(h.dtype)).reshape(
-        B, 1, hD, m.qk_nope_head_dim + m.qk_rope_head_dim)
-    q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = L.apply_rope(q_rope, pos[:, None], cfg.rope_theta)[:, 0]  # [B,H,r]
+    q_nope, q_rope = L.mla_query(p, h, pos[:, None], cfg)
     wkv_b = p["wkv_b"].astype(h.dtype).reshape(
         m.kv_lora_rank, hD, m.qk_nope_head_dim + m.v_head_dim)
     w_uk = wkv_b[..., :m.qk_nope_head_dim]                 # [R_c, H, nope]
     w_uv = wkv_b[..., m.qk_nope_head_dim:]                 # [R_c, H, v]
     q_lat = jnp.einsum("bhn,rhn->bhr", q_nope[:, 0], w_uk)  # [B,H,R_c]
-    q_eff = jnp.concatenate([q_lat, q_rope], axis=-1)       # [B,H,R]
-    sm = 1.0 / ((m.qk_nope_head_dim + m.qk_rope_head_dim) ** 0.5)
+    q_eff = jnp.concatenate([q_lat, q_rope[:, 0]], axis=-1)  # [B,H,R]
+    sm = L.mla_softmax_scale(cfg)
 
     # latent "KV": key = value = latent vector (head-shared, Hkv=1)
-    cold = quantized_attention_partial(
-        q_eff, lc[:, :, None, :], ls[:, :, None], lc[:, :, None, :],
-        ls[:, :, None], new_cold, bits=bits, chunk=scfg.attn_chunk,
-        sm_scale=sm, paper_mode=not scfg.fused_dequant_attention)
-    ring_pos = _ring_positions(pos, W)
-    hot_valid = ring_pos >= new_cold[:, None]
-    latf = lat_hot.astype(jnp.float32)[:, :, None, :]       # [B,W,1,R]
-    hot = _attend_partial(q_eff, latf, latf, hot_valid, sm)
-    ctx = finish(merge_partials(cold, hot), jnp.float32)    # [B,H,R]
+    with spans.scope(spans.MLA_ATTEND):
+        cold = quantized_attention_partial(
+            q_eff, lc[:, :, None, :], ls[:, :, None], lc[:, :, None, :],
+            ls[:, :, None], new_cold, bits=bits, chunk=scfg.attn_chunk,
+            sm_scale=sm, paper_mode=not scfg.fused_dequant_attention)
+        ring_pos = _ring_positions(pos, W)
+        hot_valid = ring_pos >= new_cold[:, None]
+        latf = lat_hot.astype(jnp.float32)[:, :, None, :]   # [B,W,1,R]
+        hot = _attend_partial(q_eff, latf, latf, hot_valid, sm)
+        ctx = finish(merge_partials(cold, hot), jnp.float32)  # [B,H,R]
     ctx_c = ctx[..., :m.kv_lora_rank]
     o = jnp.einsum("bhr,rhv->bhv", ctx_c, w_uv.astype(jnp.float32))
     o = o.reshape(B, 1, hD * m.v_head_dim).astype(x.dtype)
     x = x + o @ p["wo"].astype(x.dtype)
 
     h2 = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
-    x = x + L.mlp_apply(lp["mlp"], h2)
+    x = x + mlp(h2)
     new_cache = dict(cache_l, lat_codes=lc, lat_scales=ls, lat_hot=lat_hot,
                      cold_len=new_cold)
     return x, new_cache
@@ -460,7 +469,9 @@ def decode_step(params: Params, cache: Dict[str, Any], tokens: jnp.ndarray,
             attn_cache = {k: gcache[k] for k in
                           ("k_codes", "k_scales", "v_codes", "v_scales",
                            "k_hot", "v_hot", "cold_len")}
-            xx, new_attn = gqa_decode_layer(sp, xx, attn_cache, pos, cfg, scfg)
+            xx, new_attn = gqa_decode_layer(
+                sp, xx, attn_cache, pos, cfg, scfg,
+                lambda h: L.mlp_apply(sp["mlp"], h))
             return (xx, g + 1), {"ssm": new_ssm, **new_attn}
 
         (x, _), new_cache = jax.lax.scan(
@@ -470,12 +481,11 @@ def decode_step(params: Params, cache: Dict[str, Any], tokens: jnp.ndarray,
 
     layer_fn = mla_decode_layer if cfg.attn_kind == "mla" else gqa_decode_layer
 
-    def body(x, inp):
-        lp, cl = inp
-        x, new_cl = layer_fn(lp, x, cl, pos, cfg, scfg)
-        return x, new_cl
+    def body(x, lp, i, cl):
+        return layer_fn(lp, x, cl, pos, cfg, scfg,
+                        lambda h: serve_mlp(params, lp, i, h, cfg))
 
-    x, new_cache = jax.lax.scan(body, x, (params["layers"], cache))
+    x, new_cache = T.scan_layers(body, x, params, cfg, cache)
     logits = T.unembed(params, x, cfg)[:, 0]
     return logits, new_cache
 
@@ -591,12 +601,12 @@ def prefill(params: Params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
         return last_logits(x), cache
 
     if cfg.attn_kind == "mla":
-        def body(x, lp):
+        def body(x, lp, i):
             h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
             lat = L.mla_latent(lp["attn"], h, pos, cfg)        # [B,S,R]
             x = x + L.mla_attend(lp["attn"], h, lat, pos, cfg, causal=True)
             h2 = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
-            x = x + L.mlp_apply(lp["mlp"], h2)
+            x = x + serve_mlp(params, lp, i, h2, cfg)
             R = lat.shape[-1]
             pad = max_len - S
             latp = jnp.pad(lat, ((0, 0), (0, pad), (0, 0)))
@@ -608,22 +618,17 @@ def prefill(params: Params, batch: Dict[str, jnp.ndarray], cfg: ModelConfig,
             return x, {"lat_codes": c, "lat_scales": s[..., 0],
                        "lat_hot": lat_hot,
                        "cold_len": jnp.maximum(lens_arr - W, 0)}
-        x, cache = jax.lax.scan(body, x, params["layers"])
+        x, cache = T.scan_layers(body, x, params, cfg)
         return last_logits(x), cache
 
-    def body(x, lp):
+    def body(x, lp, i):
         h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
         k, v = L.gqa_project_kv(lp["attn"], h, pos, cfg)
         q = L.gqa_project_q(lp["attn"], h, pos, cfg)
         o = L.chunked_attention(q, k, v, causal=True)
         x = x + L.gqa_output(lp["attn"], o, cfg)
         h2 = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
-        if cfg.family == "moe":
-            from repro.models.moe import moe_apply
-            y, _ = moe_apply(lp["mlp"], h2, cfg)
-        else:
-            y = L.mlp_apply(lp["mlp"], h2)
-        return x + y, fill_gqa(k, v)
+        return x + serve_mlp(params, lp, i, h2, cfg), fill_gqa(k, v)
 
-    x, cache = jax.lax.scan(body, x, params["layers"])
+    x, cache = T.scan_layers(body, x, params, cfg)
     return last_logits(x), cache

@@ -7,12 +7,14 @@ in common/sharding.py (MaxText-style logical axes).
 from __future__ import annotations
 
 import functools
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 
-from repro.common.types import MLAConfig, ModelConfig
+from repro.common.types import MLAConfig, ModelConfig, YaRNConfig
 
 Params = Dict[str, Any]
 Axes = Dict[str, Any]
@@ -46,10 +48,38 @@ def rope_freqs(dim: int, theta: float) -> jnp.ndarray:
     return 1.0 / (theta ** (jnp.arange(0, dim, 2, dtype=jnp.float32) / dim))
 
 
-def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float) -> jnp.ndarray:
-    """x [..., S, H, D] (D even), positions [..., S] -> rotated x."""
+def yarn_mscale(scale: float, mscale: float) -> float:
+    """YaRN's attention-temperature factor 0.1 * mscale * ln(scale) + 1."""
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def yarn_freqs(dim: int, theta: float, y: YaRNConfig) -> np.ndarray:
+    """YaRN inverse frequencies [dim/2]: the plain ones (extrapolation) for
+    the fast dims, the plain ones / factor (interpolation) for the slow
+    ones, and a linear ramp between the dims where a rotation over the
+    original context turns ``beta_fast`` and ``beta_slow`` times."""
+    i = np.arange(0, dim, 2, dtype=np.float64) / dim
+    extra = 1.0 / theta ** i
+    inter = extra / y.factor
+
+    def corr(rot):
+        return dim * math.log(y.original_max_position_embeddings
+                              / (rot * 2 * math.pi)) / (2 * math.log(theta))
+    low = max(math.floor(corr(y.beta_fast)), 0)
+    high = min(math.ceil(corr(y.beta_slow)), dim - 1)
+    if low == high:
+        high += 0.001
+    keep = 1.0 - np.clip((np.arange(dim // 2) - low) / (high - low), 0, 1)
+    return (inter * (1 - keep) + extra * keep).astype(np.float32)
+
+
+def apply_rope(x: jnp.ndarray, positions: jnp.ndarray, theta: float,
+               freqs=None) -> jnp.ndarray:
+    """x [..., S, H, D] (D even), positions [..., S] -> rotated x; ``freqs``
+    [D/2] overrides the plain inverse frequencies."""
     d = x.shape[-1]
-    freqs = rope_freqs(d, theta)                       # [D/2]
+    if freqs is None:
+        freqs = rope_freqs(d, theta)                   # [D/2]
     angles = positions[..., None].astype(jnp.float32) * freqs  # [..., S, D/2]
     cos = jnp.cos(angles)[..., None, :]                # [..., S, 1, D/2]
     sin = jnp.sin(angles)[..., None, :]
@@ -179,20 +209,68 @@ def mla_init(key, cfg: ModelConfig) -> Tuple[Params, Axes]:
     qk = m.qk_nope_head_dim + m.qk_rope_head_dim
     ks = jax.random.split(key, 6)
     params = {
-        "wq_a": _init(ks[0], (d, m.q_lora_rank)),
-        "wq_b": _init(ks[1], (m.q_lora_rank, h * qk)),
         "wkv_a": _init(ks[2], (d, m.kv_lora_rank + m.qk_rope_head_dim)),
         "wkv_b": _init(ks[3], (m.kv_lora_rank,
                                h * (m.qk_nope_head_dim + m.v_head_dim))),
         "wo": _init(ks[4], (h * m.v_head_dim, d),
                     scale=1.0 / ((h * m.v_head_dim) ** 0.5)),
-        "q_norm": jnp.ones((m.q_lora_rank,), jnp.float32),
         "kv_norm": jnp.ones((m.kv_lora_rank,), jnp.float32),
     }
-    axes = {"wq_a": ("fsdp", "latent"), "wq_b": ("latent", "heads"),
-            "wkv_a": ("fsdp", "latent"), "wkv_b": ("latent", "heads"),
-            "wo": ("heads", "fsdp"), "q_norm": ("latent",), "kv_norm": ("latent",)}
+    axes = {"wkv_a": ("fsdp", "latent"), "wkv_b": ("latent", "heads"),
+            "wo": ("heads", "fsdp"), "kv_norm": ("latent",)}
+    if m.q_lora_rank:
+        params.update(wq_a=_init(ks[0], (d, m.q_lora_rank)),
+                      wq_b=_init(ks[1], (m.q_lora_rank, h * qk)),
+                      q_norm=jnp.ones((m.q_lora_rank,), jnp.float32))
+        axes.update(wq_a=("fsdp", "latent"), wq_b=("latent", "heads"),
+                    q_norm=("latent",))
+    else:
+        params["wq"] = _init(ks[0], (d, h * qk))
+        axes["wq"] = ("fsdp", "heads")
     return params, axes
+
+
+def mla_rope(x: jnp.ndarray, positions: jnp.ndarray,
+             cfg: ModelConfig) -> jnp.ndarray:
+    """RoPE of MLA's rope dims, YaRN-scaled where the config says so.
+    Rotate-half over the dims as they are laid out; the published model
+    rotates interleaved pairs, which with weights drawn here is a fixed
+    permutation of the projection's columns."""
+    y = (cfg.mla or MLAConfig()).yarn
+    if y is None:
+        return apply_rope(x, positions, cfg.rope_theta)
+    out = apply_rope(x, positions, cfg.rope_theta,
+                     yarn_freqs(x.shape[-1], cfg.rope_theta, y))
+    mag = yarn_mscale(y.factor, y.mscale) / \
+        yarn_mscale(y.factor, y.mscale_all_dim)
+    return out if mag == 1.0 else (out * mag).astype(x.dtype)
+
+
+def mla_softmax_scale(cfg: ModelConfig) -> float:
+    """1/sqrt(qk head dim), times mscale(factor, mscale_all_dim)^2 under
+    YaRN."""
+    m = cfg.mla or MLAConfig()
+    sm = 1.0 / ((m.qk_nope_head_dim + m.qk_rope_head_dim) ** 0.5)
+    if m.yarn is not None and m.yarn.mscale_all_dim:
+        sm *= yarn_mscale(m.yarn.factor, m.yarn.mscale_all_dim) ** 2
+    return sm
+
+
+def mla_query(p: Params, x: jnp.ndarray, positions: jnp.ndarray,
+              cfg: ModelConfig) -> Tuple[jnp.ndarray, jnp.ndarray]:
+    """Queries of new tokens x [B,T,d]: (q_nope [B,T,H,nope], q_rope
+    [B,T,H,rope] rotated), through the q-LoRA or one full projection."""
+    m = cfg.mla or MLAConfig()
+    B, T, _ = x.shape
+    if m.q_lora_rank:
+        q = rms_norm(x @ p["wq_a"].astype(x.dtype), p["q_norm"], cfg.norm_eps)
+        q = q @ p["wq_b"].astype(x.dtype)
+    else:
+        q = x @ p["wq"].astype(x.dtype)
+    q = q.reshape(B, T, cfg.num_heads,
+                  m.qk_nope_head_dim + m.qk_rope_head_dim)
+    q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
+    return q_nope, mla_rope(q_rope, positions, cfg)
 
 
 def mla_latent(p: Params, x: jnp.ndarray, positions: jnp.ndarray,
@@ -204,7 +282,7 @@ def mla_latent(p: Params, x: jnp.ndarray, positions: jnp.ndarray,
     ckv = x @ p["wkv_a"].astype(x.dtype)
     c, k_rope = jnp.split(ckv, [m.kv_lora_rank], axis=-1)
     c = rms_norm(c, p["kv_norm"], cfg.norm_eps)
-    k_rope = apply_rope(k_rope[:, :, None, :], positions, cfg.rope_theta)[:, :, 0]
+    k_rope = mla_rope(k_rope[:, :, None, :], positions, cfg)[:, :, 0]
     return jnp.concatenate([c, k_rope], axis=-1)
 
 
@@ -216,11 +294,7 @@ def mla_attend(p: Params, x: jnp.ndarray, latent: jnp.ndarray,
     h = cfg.num_heads
     B, T, _ = x.shape
     S = latent.shape[1]
-    q = rms_norm(x @ p["wq_a"].astype(x.dtype), p["q_norm"], cfg.norm_eps)
-    q = (q @ p["wq_b"].astype(x.dtype)).reshape(
-        B, T, h, m.qk_nope_head_dim + m.qk_rope_head_dim)
-    q_nope, q_rope = jnp.split(q, [m.qk_nope_head_dim], axis=-1)
-    q_rope = apply_rope(q_rope, positions, cfg.rope_theta)
+    q_nope, q_rope = mla_query(p, x, positions, cfg)
     c, k_rope = jnp.split(latent, [m.kv_lora_rank], axis=-1)
     kv = (c @ p["wkv_b"].astype(x.dtype)).reshape(
         B, S, h, m.qk_nope_head_dim + m.v_head_dim)
@@ -229,8 +303,8 @@ def mla_attend(p: Params, x: jnp.ndarray, latent: jnp.ndarray,
                          jnp.broadcast_to(k_rope[:, :, None, :],
                                           (B, S, h, m.qk_rope_head_dim))], axis=-1)
     qq = jnp.concatenate([q_nope, q_rope], axis=-1)
-    sm = 1.0 / ((m.qk_nope_head_dim + m.qk_rope_head_dim) ** 0.5)
-    o = chunked_attention(qq, k, v, causal=causal, sm_scale=sm)
+    o = chunked_attention(qq, k, v, causal=causal,
+                          sm_scale=mla_softmax_scale(cfg))
     return o.reshape(B, T, h * m.v_head_dim) @ p["wo"].astype(x.dtype)
 
 

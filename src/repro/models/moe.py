@@ -1,11 +1,21 @@
-"""Mixture-of-Experts layer (qwen3-moe: 128e top-8; arctic: 128e top-2 +
-dense residual).
+"""Mixture-of-Experts layers (qwen3-moe: 128e top-8; arctic: 128e top-2 +
+dense residual; DeepSeek-V2: 64e top-6 + 2 shared experts).
 
-Sort-based capacity dispatch (the production TPU pattern): tokens are grouped
-by expert with a single argsort, truncated at capacity C = ceil(k*N/E * cf),
-processed as one [E, C, D] batched einsum (experts sharded on the "expert"
-logical axis -> EP over "model"), and gathered back differentiably. No
-[N, E, C] one-hot tensors are ever materialized."""
+One router (``_route``: float32 scores, softmax, top-k) and two dispatches
+over the same parameters:
+
+* ``moe_apply_dropless`` (serving: prefill and decode) drops no token. The
+  (token, choice) pairs are sorted by expert and each expert's rows go
+  through its three matrices as one grouped product
+  (``jax.lax.ragged_dot``), so every routed pair is computed whatever the
+  load. It retires the sort-based capacity dispatch (``moe_apply_sorted``)
+  that serving used, which dropped the pairs beyond C = ceil(k*N/E*1.25).
+* ``moe_apply_grouped`` (training): GShard-style one-hot dispatch in
+  groups, truncated at capacity (see its docstring).
+
+Shared experts (DeepSeek-V2) are one SwiGLU of width ``num_shared_experts
+* expert_d_ff`` that every token passes through, added to the routed sum;
+arctic's dense residual likewise."""
 from __future__ import annotations
 
 from typing import Any, Dict, Tuple
@@ -15,6 +25,7 @@ import jax.numpy as jnp
 
 from repro.common.types import ModelConfig, MoEConfig
 from repro.models.layers import _init, mlp_apply, mlp_init
+from repro.obs import spans
 
 CAPACITY_FACTOR = 1.25
 
@@ -36,7 +47,39 @@ def moe_init(key, cfg: ModelConfig) -> Tuple[Dict[str, Any], Dict[str, Any]]:
         dp, da = mlp_init(ks[4], d, mo.dense_d_ff or cfg.d_ff)
         params["dense"] = dp
         axes["dense"] = da
+    if mo.num_shared_experts:
+        sp, sa = mlp_init(ks[4], d, mo.num_shared_experts * f)
+        params["shared"] = sp
+        axes["shared"] = sa
     return params, axes
+
+
+def _route(p: Dict[str, Any], x: jnp.ndarray, mo: MoEConfig):
+    """Router over tokens x [..., D], in float32 as the published gates:
+    (probs [..., E], top-k weights [..., k], top-k experts [..., k]). The
+    weights are renormalised to 1 where ``norm_topk_prob``, then scaled by
+    ``routed_scaling_factor``."""
+    logits = jnp.dot(x.astype(jnp.float32), p["router"].astype(jnp.float32))
+    probs = jax.nn.softmax(logits, axis=-1)
+    top_p, top_i = jax.lax.top_k(probs, mo.top_k)
+    if mo.norm_topk_prob:
+        top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    if mo.routed_scaling_factor != 1.0:
+        top_p = top_p * mo.routed_scaling_factor
+    return probs, top_p, top_i
+
+
+def _unrouted(p: Dict[str, Any], x: jnp.ndarray, mo: MoEConfig
+              ) -> jnp.ndarray:
+    """What every token gets beside its routed experts: the shared experts
+    and arctic's dense residual (0 where the layer has neither)."""
+    out = jnp.zeros_like(x)
+    if "shared" in p:
+        with spans.scope(spans.MOE_SHARED):
+            out = out + mlp_apply(p["shared"], x)
+    if mo.dense_residual and "dense" in p:
+        out = out + mlp_apply(p["dense"], x)
+    return out
 
 
 GROUP_TOKENS = 512   # grouped dispatch: tokens per routing group
@@ -63,10 +106,7 @@ def moe_apply_grouped(p: Dict[str, Any], x: jnp.ndarray, cfg: ModelConfig
     cap = max(1, int(-(-(k * sg * CAPACITY_FACTOR) // e)))
 
     xg = x.reshape(g, sg, d)
-    logits = (xg @ p["router"].astype(x.dtype)).astype(jnp.float32)  # [G,Sg,E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, k)                           # [G,Sg,k]
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
+    probs, top_p, top_i = _route(p, xg, mo)                          # [G,Sg,k]
 
     dispatch = jnp.zeros((g, sg, e, cap), jnp.bool_)
     combine = jnp.zeros((g, sg, e, cap), jnp.float32)
@@ -89,75 +129,56 @@ def moe_apply_grouped(p: Dict[str, Any], x: jnp.ndarray, cfg: ModelConfig
         * jnp.einsum("egcd,edf->egcf", xe, p["wi"].astype(x.dtype))
     ye = jnp.einsum("egcf,efd->egcd", h, p["wo"].astype(x.dtype))
     out = jnp.einsum("gsec,egcd->gsd", combine.astype(x.dtype), ye)
-    out = out.reshape(B, S, d)
-
-    if mo.dense_residual and "dense" in p:
-        out = out + mlp_apply(p["dense"], x)
+    out = out.reshape(B, S, d) + _unrouted(p, x, mo)
     frac = jnp.mean(dispatch.any(-1).astype(jnp.float32), axis=(0, 1))
     mprob = jnp.mean(probs, axis=(0, 1))
     aux = jnp.sum(frac * mprob) * e * mo.load_balance_coef
     return out, aux
 
 
-def moe_apply(p: Dict[str, Any], x: jnp.ndarray, cfg: ModelConfig
-              ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """x [B,S,D] -> (out [B,S,D], aux load-balance loss scalar).
+def moe_apply_dropless(p: Dict[str, Any], x: jnp.ndarray, cfg: ModelConfig,
+                       layer=None) -> jnp.ndarray:
+    """Serving: x [B,S,D] -> out [B,S,D], every routed (token, expert) pair
+    computed. The pairs are sorted by expert (a stable argsort, so a
+    token's rows keep their order within an expert), each expert's
+    contiguous rows go through its SwiGLU as grouped products over the
+    stacked weights, and the rows return to their pairs and sum with their
+    router weights in float32.
 
-    Dispatches to the grouped einsum path for multi-token inputs (the
-    distributed-friendly default); single-token decode keeps the sort-based
-    path (tiny n, no dispatch-matmul overhead)."""
-    B, S, _ = x.shape
-    if B * S >= 2 * GROUP_TOKENS:
-        return moe_apply_grouped(p, x, cfg)
-    return moe_apply_sorted(p, x, cfg)
-
-
-def moe_apply_sorted(p: Dict[str, Any], x: jnp.ndarray, cfg: ModelConfig
-                     ) -> Tuple[jnp.ndarray, jnp.ndarray]:
-    """Sort-based capacity dispatch (single-host / decode path)."""
+    ``layer`` (a traced index): ``p`` holds every MoE layer's parameters
+    stacked on a leading axis and this is layer ``layer``'s call. The
+    experts' matrices then enter the grouped products whole, as
+    ``layers x experts`` groups of which only this layer's hold rows: a
+    sliced operand of a grouped product is copied out of the stack first
+    (on a v5e, 352 MiB a matrix a layer a step at DeepSeek-V2-Lite's
+    widths), an empty group costs nothing."""
     mo = cfg.moe or MoEConfig()
     B, S, d = x.shape
     e, k = mo.num_experts, mo.top_k
     n = B * S
-    cap = max(1, int(-(-(k * n * CAPACITY_FACTOR) // e)))
-
     xf = x.reshape(n, d)
-    logits = (xf @ p["router"].astype(x.dtype)).astype(jnp.float32)  # [N,E]
-    probs = jax.nn.softmax(logits, axis=-1)
-    top_p, top_i = jax.lax.top_k(probs, k)                           # [N,k]
-    top_p = top_p / jnp.sum(top_p, axis=-1, keepdims=True)
-
-    # group (token, choice) pairs by expert
-    flat_e = top_i.reshape(n * k)
-    flat_tok = jnp.repeat(jnp.arange(n), k)
-    order = jnp.argsort(flat_e)
-    sort_e = flat_e[order]
-    sort_tok = flat_tok[order]
-    counts = jnp.bincount(flat_e, length=e)
-    starts = jnp.cumsum(counts) - counts
-    pos = jnp.arange(n * k) - starts[sort_e]
-    keep = pos < cap
-    slot = jnp.where(keep, sort_e * cap + pos, e * cap)  # drop slot = e*cap
-
-    # dispatch: xe [E*C+1, D] (last row is the drop bin)
-    xe = jnp.zeros((e * cap + 1, d), x.dtype).at[slot].add(xf[sort_tok])
-    xe = xe[:-1].reshape(e, cap, d)
-    h = jax.nn.silu(jnp.einsum("ecd,edf->ecf", xe, p["wg"].astype(x.dtype))) \
-        * jnp.einsum("ecd,edf->ecf", xe, p["wi"].astype(x.dtype))
-    ye = jnp.einsum("ecf,efd->ecd", h, p["wo"].astype(x.dtype))
-
-    # gather back (unsort) with routing weights; dropped pairs contribute 0
-    ye_flat = jnp.concatenate([ye.reshape(e * cap, d),
-                               jnp.zeros((1, d), x.dtype)], axis=0)
-    contrib = ye_flat[slot]                                           # [N*k, D]
-    unsorted = jnp.zeros((n * k, d), x.dtype).at[order].set(contrib)
-    w = top_p.reshape(n, k).astype(x.dtype)
-    out = jnp.einsum("nkd,nk->nd", unsorted.reshape(n, k, d), w).reshape(B, S, d)
-
-    if mo.dense_residual and "dense" in p:
-        out = out + mlp_apply(p["dense"], x)
-    # switch-style aux loss over the *routed* (pre-drop) assignment
-    frac = counts.astype(jnp.float32) / (n * k)
-    mprob = jnp.mean(probs, axis=0)
-    aux = jnp.sum(frac * mprob) * e * mo.load_balance_coef
-    return out, aux
+    w = {m: p[m] for m in ("wg", "wi", "wo")}
+    if layer is not None:
+        n_layers = p["wg"].shape[0]
+        w = {m: a.reshape((n_layers * e,) + a.shape[2:]) for m, a in w.items()}
+        p = jax.tree_util.tree_map(
+            lambda a: jax.lax.dynamic_index_in_dim(a, layer, keepdims=False),
+            {m: a for m, a in p.items() if m not in w})
+    with spans.scope(spans.MOE_ROUTE):
+        _, top_p, top_i = _route(p, xf, mo)
+        flat_e = top_i.reshape(n * k)
+        order = jnp.argsort(flat_e, stable=True)
+        sizes = jnp.bincount(flat_e, length=e).astype(jnp.int32)
+        if layer is not None:
+            sizes = jax.lax.dynamic_update_slice(
+                jnp.zeros((n_layers * e,), jnp.int32), sizes, (layer * e,))
+        xs = xf[order // k]                                    # [N*k, D]
+    with spans.scope(spans.MOE_EXPERTS):
+        g = jax.lax.ragged_dot(xs, w["wg"].astype(x.dtype), sizes)
+        u = jax.lax.ragged_dot(xs, w["wi"].astype(x.dtype), sizes)
+        ys = jax.lax.ragged_dot(jax.nn.silu(g) * u,
+                                w["wo"].astype(x.dtype), sizes)  # [N*k, D]
+    with spans.scope(spans.MOE_ROUTE):
+        pairs = jnp.zeros_like(ys).at[order].set(ys).reshape(n, k, d)
+        out = jnp.einsum("nkd,nk->nd", pairs.astype(jnp.float32), top_p)
+    return out.astype(x.dtype).reshape(B, S, d) + _unrouted(p, x, mo)

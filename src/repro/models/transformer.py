@@ -3,7 +3,8 @@
 Families:
   dense  — pre-norm GQA attention + SwiGLU (deepseek/codeqwen/llama3;
            chameleon & musicgen backbones via the frontend stub)
-  moe    — GQA attention + sort-dispatch MoE (qwen3-moe, arctic)
+  moe    — attention + MoE (qwen3-moe, arctic; deepseek-v2-lite: MLA,
+           shared experts, first_k_dense leading dense layers)
   mla    — attn_kind="mla" swaps GQA for latent attention (minicpm3)
   ssm    — attention-free Mamba1 stack (falcon-mamba)
   hybrid — Mamba2 groups with shared attention blocks every attn_period
@@ -49,21 +50,24 @@ def _stack_init(fn, key, n: int):
     return params, axes
 
 
-def _layer_init(key, cfg: ModelConfig):
-    """One transformer layer (attention archs)."""
+def _layer_init(key, cfg: ModelConfig, with_mlp: bool = True):
+    """One transformer layer (attention archs); ``with_mlp=False`` leaves
+    the MLP to the stacks of ``first_k_dense`` models."""
     ks = jax.random.split(key, 4)
     if cfg.attn_kind == "mla":
         ap, aa = L.mla_init(ks[0], cfg)
     else:
         ap, aa = L.gqa_init(ks[0], cfg)
-    if cfg.family == "moe":
-        mp, ma = MOE.moe_init(ks[1], cfg)
-    else:
-        mp, ma = L.mlp_init(ks[1], cfg.d_model, cfg.d_ff)
-    params = {"attn": ap, "mlp": mp,
+    params = {"attn": ap,
               "ln1": jnp.ones((cfg.d_model,), jnp.float32),
               "ln2": jnp.ones((cfg.d_model,), jnp.float32)}
-    axes = {"attn": aa, "mlp": ma, "ln1": ("embed",), "ln2": ("embed",)}
+    axes = {"attn": aa, "ln1": ("embed",), "ln2": ("embed",)}
+    if with_mlp:
+        if cfg.family == "moe":
+            params["mlp"], axes["mlp"] = MOE.moe_init(ks[1], cfg)
+        else:
+            params["mlp"], axes["mlp"] = L.mlp_init(ks[1], cfg.d_model,
+                                                    cfg.d_ff)
     return params, axes
 
 
@@ -106,10 +110,56 @@ def init_params(key, cfg: ModelConfig) -> Tuple[Params, Dict[str, Any]]:
                 e is None or isinstance(e, str) for e in x))
         params["shared"], axes["shared"] = _stack_init(
             lambda k: _layer_init(k, cfg), ks[3], cfg.attn_shared_blocks)
+    elif cfg.first_k_dense:
+        # attention and norms of every layer in one stack; the MLPs in two,
+        # dense_mlp for the leading layers and moe for the rest
+        k = cfg.first_k_dense
+        params["layers"], axes["layers"] = _stack_init(
+            lambda kk: _layer_init(kk, cfg, with_mlp=False), ks[2],
+            cfg.num_layers)
+        params["dense_mlp"], axes["dense_mlp"] = _stack_init(
+            lambda kk: L.mlp_init(kk, cfg.d_model, cfg.d_ff), ks[3], k)
+        params["moe"], axes["moe"] = _stack_init(
+            lambda kk: MOE.moe_init(kk, cfg), ks[4], cfg.num_layers - k)
     else:
         params["layers"], axes["layers"] = _stack_init(
             lambda k: _layer_init(k, cfg), ks[2], cfg.num_layers)
     return params, axes
+
+
+def scan_layers(fn, x, params: Params, cfg: ModelConfig, *xs):
+    """``lax.scan`` of ``fn(carry, lp, i, *xs_l)`` over the layers, where
+    ``lp`` is the layer's slice of ``params["layers"]`` and ``xs`` more
+    per-layer inputs (a cache). ``i``, the layer's index, is scanned only
+    where ``first_k_dense`` makes the MLP differ by layer, and is None
+    otherwise, so that such a model scans exactly its layers and ``xs``."""
+    if cfg.first_k_dense:
+        return jax.lax.scan(lambda c, a: fn(c, a[0], a[1], *a[2:]), x,
+                            (params["layers"], jnp.arange(cfg.num_layers))
+                            + xs)
+    return jax.lax.scan(lambda c, a: fn(c, a[0], None, *a[1:]), x,
+                        (params["layers"],) + xs)
+
+
+def layer_mlp(params: Params, lp: Params, i, h: jnp.ndarray,
+              cfg: ModelConfig, dense_fn, moe_fn):
+    """The MLP of layer ``i`` applied to ``h``: ``dense_fn(p, h)`` or, in
+    the moe family, ``moe_fn(p, h, None)``. Behind ``first_k_dense`` leading
+    dense layers, layer ``i`` takes ``params["dense_mlp"][i]`` below k and
+    otherwise ``moe_fn(params["moe"], h, i - k)``, the whole stack and the
+    index into it, through one ``lax.cond``."""
+    k = cfg.first_k_dense
+    if not k:
+        if cfg.family == "moe":
+            return moe_fn(lp["mlp"], h, None)
+        return dense_fn(lp["mlp"], h)
+    dense = jax.tree_util.tree_map(
+        lambda a: jax.lax.dynamic_index_in_dim(a, jnp.minimum(i, k - 1),
+                                               keepdims=False),
+        params["dense_mlp"])
+    return jax.lax.cond(i < k, lambda: dense_fn(dense, h),
+                        lambda: moe_fn(params["moe"], h,
+                                       jnp.maximum(i - k, 0)))
 
 
 # ---------------------------------------------------------------------------
@@ -134,7 +184,8 @@ def unembed(params: Params, x: jnp.ndarray, cfg: ModelConfig) -> jnp.ndarray:
 # Train / prefill forward (full sequence)
 # ---------------------------------------------------------------------------
 
-def _attn_block(lp: Params, x: jnp.ndarray, cfg: ModelConfig
+def _attn_block(lp: Params, x: jnp.ndarray, cfg: ModelConfig,
+                params: Optional[Params] = None, i=None
                 ) -> Tuple[jnp.ndarray, jnp.ndarray]:
     h = L.rms_norm(x, lp["ln1"], cfg.norm_eps)
     if cfg.attn_kind == "mla":
@@ -142,10 +193,14 @@ def _attn_block(lp: Params, x: jnp.ndarray, cfg: ModelConfig
     else:
         x = x + L.gqa_apply_train(lp["attn"], h, cfg)
     h = L.rms_norm(x, lp["ln2"], cfg.norm_eps)
-    if cfg.family == "moe":
-        y, aux = MOE.moe_apply(lp["mlp"], h, cfg)
-    else:
-        y, aux = L.mlp_apply(lp["mlp"], h), jnp.float32(0)
+    def moe(p, h, j):
+        if j is not None:
+            p = jax.tree_util.tree_map(
+                lambda a: jax.lax.dynamic_index_in_dim(a, j, keepdims=False),
+                p)
+        return MOE.moe_apply_grouped(p, h, cfg)
+    y, aux = layer_mlp(params, lp, i, h, cfg,
+                       lambda p, h: (L.mlp_apply(p, h), jnp.float32(0)), moe)
     return x + y, aux
 
 
@@ -194,14 +249,14 @@ def forward(params: Params, batch: Dict[str, jnp.ndarray],
         (x, _), _ = jax.lax.scan(group_body, (x, jnp.int32(0)), params["layers"])
         return unembed(params, x, cfg), jnp.float32(0)
 
-    def body(carry, lp):
+    def body(carry, lp, i):
         x, aux = carry
-        fn = functools.partial(_attn_block, cfg=cfg)
+        fn = functools.partial(_attn_block, cfg=cfg, params=params)
         if cfg.remat:
             fn = jax.checkpoint(fn)
-        x, a = fn(lp, x)
+        x, a = fn(lp, x, i=i)
         return (x, aux + a), None
-    (x, aux), _ = jax.lax.scan(body, (x, jnp.float32(0)), params["layers"])
+    (x, aux), _ = scan_layers(body, (x, jnp.float32(0)), params, cfg)
     return unembed(params, x, cfg), aux
 
 

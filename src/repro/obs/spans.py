@@ -2,8 +2,10 @@
 
 This module is the one place where they are declared; each name carries
 the per-layer metric it is for (``bench/metrics/``). The scopes are read
-by ``bench/hlo_scopes.py``; the benchmark's trace reduction does not read
-the serve spans yet, so the serve metrics named below have no reader.
+by ``bench/hlo_scopes.py`` (the replay window's, ``SCOPES``) and
+``bench/decode_scopes.py`` (the serve model's, ``SERVE_SCOPES``); the
+benchmark's trace reduction does not read the serve spans yet, so the
+serve metrics named below have no reader.
 
 * **Scopes** (``scope``) are ``jax.named_scope``s inside jitted code. They
   change only the HLO ``op_name`` metadata of the ops traced under them, so
@@ -26,7 +28,16 @@ CLASSIFY = "ibex.classify"    # replay.classify_us_per_access
 MCACHE = "ibex.mcache"        # replay.mcache_us_per_access
 DRAIN = "ibex.drain"          # replay.drain_us_per_access
 
-SCOPES = (TOPUP, CLASSIFY, MCACHE, DRAIN)
+SCOPES = (TOPUP, CLASSIFY, MCACHE, DRAIN)   # read by bench/hlo_scopes.py
+
+# -- device scopes: the serve model's expert layer and latent attention -----
+MOE_ROUTE = "moe.route"       # router, sort by expert and combine
+MOE_EXPERTS = "moe.experts"   # serve.moe_roofline.v2lite (grouped products)
+MOE_SHARED = "moe.shared"     # the shared experts
+MLA_ATTEND = "mla.attend"     # serve.mla_attn_roofline.v2lite (decode step)
+
+# read by bench/decode_scopes.py, for the decode-step program
+SERVE_SCOPES = (MOE_ROUTE, MOE_EXPERTS, MOE_SHARED, MLA_ATTEND)
 
 # -- host spans: the serve engine (serve/engine.py) --------------------------
 STEP = "engine.step"          # root of every serve span path (span_s, span_n)

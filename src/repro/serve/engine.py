@@ -43,7 +43,10 @@ prefilled together, grouped into power-of-two length buckets (right-padded;
 ``models/decode.prefill``'s ``lens`` argument keeps padded positions out of
 the cache's valid range, so a padded row decodes identically to an unpadded
 one). Attention-family models bucket freely; ssm/hybrid models group by
-exact length only (right-padding would pollute the recurrent state).
+exact length only (right-padding would pollute the recurrent state). A
+``prefill_budget`` caps the prompt tokens of one call: a bucket's group is
+then split into calls of at most that many tokens, so that long prompts do
+not cap the lane count at (all lanes x the largest bucket).
 
 Scheduling: FIFO admission, at most one preemption per engine step. All
 cache motion is counted in ``self.counters`` (bytes and events) for
@@ -533,54 +536,69 @@ class Engine(_EngineBase):
         if fresh:
             self._start_fresh(fresh)
 
+    def _prefill_calls(self, L: int, grp: list) -> list:
+        """A bucket's admission group as prefill calls: one call, or where
+        ``prefill_budget`` is set, calls of at most that many prompt tokens
+        (a power of two of rows, at least one)."""
+        budget = self.scfg.prefill_budget
+        if not budget:
+            return [grp]
+        rows = 1 << (max(budget // L, 1).bit_length() - 1)
+        return [grp[i:i + rows] for i in range(0, len(grp), rows)]
+
     def _start_fresh(self, items) -> None:
         """Batched prefill of all fresh admissions, grouped into length
-        buckets — one compile and one host sync per bucket instead of one
-        per request."""
+        buckets — one compile and one host sync per bucket (per call of the
+        prefill budget) instead of one per request."""
         groups: Dict[int, list] = {}
         for rid, lane in items:
             L = self._bucket(len(self.requests[rid].prompt))
             groups.setdefault(L, []).append((rid, lane))
-        for L, grp in sorted(groups.items()):
-            with spans.span(spans.PREFILL, bucket=L, rows=len(grp)):
-                k = len(grp)
-                Bp = _next_pow2(k)      # pad rows too: fewer compiled shapes
-                tokens = np.zeros((Bp, L), np.int32)
-                lens = np.ones((Bp,), np.int32)
-                for i, (rid, _) in enumerate(grp):
-                    p = self.requests[rid].prompt
-                    tokens[i, :len(p)] = p
-                    lens[i] = len(p)
-                batch = {"tokens": jnp.asarray(tokens)}
-                if self.cfg.frontend != "none":
-                    batch["embeds"] = jnp.zeros((Bp, L, self.cfg.d_model),
-                                                jnp.bfloat16)
-                toks, sub = self._prefill_fn(self.params, batch,
-                                             jnp.asarray(lens))
-                lanes_arr = jnp.asarray([lane for _, lane in grp])
-                ax = _ssm_batch_axis(self.cache)
-                real = {kk: (jax.tree_util.tree_map(
-                            lambda a: jax.lax.slice_in_dim(a, 0, k, axis=ax),
-                            vv) if kk == "ssm" else vv[:, :k])
-                        for kk, vv in sub.items()}
-                self.cache = _lanes_install(self.cache, lanes_arr, real)
-                toks_h = self._fetch(toks[:k], "admit_syncs")
-                self.counters["prefill_batches"] += 1
-                for i, (rid, lane) in enumerate(grp):
-                    req = self.requests[rid]
-                    req.generated.append(int(toks_h[i]))
-                    req.pos = int(lens[i])
-                    req.lane = lane
-                    req.state = RUNNING
-                    self.counters["promotions"] += 1
-                    remaining = req.max_new_tokens - 1
-                    if remaining <= 0 or req.pos >= self.max_len - 1:
-                        req.state = DONE
-                        req.lane = -1
-                        self.lane_req[lane] = None
-                    else:
-                        self._set_lane_state(lane, int(toks_h[i]), req.pos,
-                                             remaining)
+        for L, bucket_grp in sorted(groups.items()):
+            for grp in self._prefill_calls(L, bucket_grp):
+                self._prefill_group(L, grp)
+
+    def _prefill_group(self, L: int, grp: list) -> None:
+        """One prefill call of ``grp``'s prompts, right-padded to ``L``."""
+        with spans.span(spans.PREFILL, bucket=L, rows=len(grp)):
+            k = len(grp)
+            Bp = _next_pow2(k)      # pad rows too: fewer compiled shapes
+            tokens = np.zeros((Bp, L), np.int32)
+            lens = np.ones((Bp,), np.int32)
+            for i, (rid, _) in enumerate(grp):
+                p = self.requests[rid].prompt
+                tokens[i, :len(p)] = p
+                lens[i] = len(p)
+            batch = {"tokens": jnp.asarray(tokens)}
+            if self.cfg.frontend != "none":
+                batch["embeds"] = jnp.zeros((Bp, L, self.cfg.d_model),
+                                            jnp.bfloat16)
+            toks, sub = self._prefill_fn(self.params, batch,
+                                         jnp.asarray(lens))
+            lanes_arr = jnp.asarray([lane for _, lane in grp])
+            ax = _ssm_batch_axis(self.cache)
+            real = {kk: (jax.tree_util.tree_map(
+                        lambda a: jax.lax.slice_in_dim(a, 0, k, axis=ax),
+                        vv) if kk == "ssm" else vv[:, :k])
+                    for kk, vv in sub.items()}
+            self.cache = _lanes_install(self.cache, lanes_arr, real)
+            toks_h = self._fetch(toks[:k], "admit_syncs")
+            self.counters["prefill_batches"] += 1
+            for i, (rid, lane) in enumerate(grp):
+                req = self.requests[rid]
+                req.generated.append(int(toks_h[i]))
+                req.pos = int(lens[i])
+                req.lane = lane
+                req.state = RUNNING
+                self.counters["promotions"] += 1
+                remaining = req.max_new_tokens - 1
+                if remaining <= 0 or req.pos >= self.max_len - 1:
+                    req.state = DONE
+                    req.lane = -1
+                    self.lane_req[lane] = None
+                else:
+                    self._set_lane_state(lane, int(toks_h[i]), req.pos,
+                                         remaining)
 
     def _preempt(self, lane: int) -> None:
         """Demote the lane. A shadow still covering every token short-

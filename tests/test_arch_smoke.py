@@ -23,9 +23,11 @@ def _batch(cfg):
     return batch
 
 
-# tier-1 keeps three cheap, family-diverse configs (dense/GQA, MLA, audio
-# frontend); the rest are slow-marked and run with `pytest -m ""`
-_FAST_ARCHS = {"llama3_8b", "minicpm3_4b", "musicgen_medium"}
+# tier-1 keeps four cheap, family-diverse configs (dense/GQA, MLA, audio
+# frontend, MLA + shared experts behind a dense layer); the rest are
+# slow-marked and run with `pytest -m ""`
+_FAST_ARCHS = {"llama3_8b", "minicpm3_4b", "musicgen_medium",
+               "deepseek_v2_lite"}
 _ARCH_PARAMS = [a if a in _FAST_ARCHS else
                 pytest.param(a, marks=pytest.mark.slow) for a in ARCH_IDS]
 
@@ -78,6 +80,7 @@ def test_full_config_param_counts():
         "minicpm3_4b": (2.5e9, 6e9), "codeqwen15_7b": (5e9, 9e9),
         "llama3_8b": (6e9, 10e9), "zamba2_2p7b": (2e9, 4.5e9),
         "musicgen_medium": (1e9, 2.5e9), "falcon_mamba_7b": (5e9, 9e9),
+        "deepseek_v2_lite": (15e9, 16.5e9),
     }
     for arch, (lo, hi) in expect.items():
         n = get_config(arch).param_count()

@@ -202,3 +202,29 @@ def test_replay_window_scopes_leave_the_program_unchanged(monkeypatch):
     bare = _optimized_hlo(cfg, pool, xs)
     assert not any(name in bare for name in spans.SCOPES)
     assert normalized(scoped) == normalized(bare)
+
+
+def _v2lite_step_hlo():
+    from repro.models import decode as D
+    cfg = get_reduced("deepseek_v2_lite")
+    scfg = ServeConfig(max_running=2, hot_window=8, attn_chunk=32)
+    params = jax.eval_shape(lambda k: T.init_params(k, cfg)[0],
+                            jax.random.PRNGKey(0))
+    cache = jax.eval_shape(lambda: D.init_cache(cfg, scfg, 2, 64))
+    vec = jax.ShapeDtypeStruct((2,), jnp.int32)
+    jax.clear_caches()
+    return jax.jit(lambda p, c, t, pos: D.decode_step(
+        p, c, t, pos, cfg, scfg)).lower(params, cache, vec,
+                                        vec).compile().as_text()
+
+
+def test_serve_scopes_leave_the_decode_step_unchanged(monkeypatch):
+    """The expert layer's and the latent attention's scopes in the decode
+    step of an MLA + MoE model: present, and nothing else changes."""
+    scoped = _v2lite_step_hlo()
+    for name in spans.SERVE_SCOPES:
+        assert f"/{name}/" in scoped, name
+    monkeypatch.setattr(spans, "scope", lambda name: contextlib.nullcontext())
+    bare = _v2lite_step_hlo()
+    assert not any(name in bare for name in spans.SERVE_SCOPES)
+    assert normalized(scoped) == normalized(bare)
