@@ -19,6 +19,16 @@ The engine is the request-granular face of the paper's pool:
     **zero bytes**: the shadow is simply re-validated, like ``shadow_valid``
     pages in ``core/engine/ops.py``. Demotion cost is proportional to new
     tokens, never to context length;
+  * **the copy moves a token range, not the lane.** A park fetches positions
+    ``[shadow_pos, pos)`` of each leaf with a token axis (``[0, pos)`` at a
+    request's first park) and writes them into the request's host buffer:
+    one token-major array per leaf, made full-length and zero at the first
+    park, which ``Request.parked`` shows as ``[layers, max_len, ...]``. A
+    resume uploads the prefix ``[0, pos)`` and installs it into the lane in
+    place (the cache is donated); positions past ``cold_len`` keep what the
+    lane held before, which attention masks. Range lengths are rounded up a
+    ladder of powers of two (``_ladder``), so each is one program, and the
+    engine compiles every rung when it is built;
   * victim selection is the §4.4 second-chance sweep over lanes (reference
     bit = "generated a token since last sweep"), vectorized over all lanes
     in one pass (``SecondChanceLanes.select_mask``).
@@ -83,6 +93,13 @@ WAITING, RUNNING, PREEMPTED, DONE = "waiting", "running", "preempted", "done"
 # bf16 hot-ring leaves: quantized into the codes region on demotion, zeroed
 # on resume — never parked, never moved
 HOT_KEYS = ("k_hot", "v_hot", "lat_hot")
+# leaves with a token axis (axis 1 of a lane's slice): a park fetches, and a
+# resume uploads, a range of their positions; ``cold_len`` and the ssm
+# state have none and move whole
+TOKEN_KEYS = ("k_codes", "k_scales", "v_codes", "v_scales", "lat_codes",
+              "lat_scales")
+# the shortest token range a park or a resume moves
+MIN_RANGE = 64
 
 
 @dataclass
@@ -94,7 +111,9 @@ class Request:
     generated: List[int] = field(default_factory=list)
     lane: int = -1
     pos: int = 0                      # next position to write
-    parked: Optional[Dict[str, Any]] = None   # demoted KV (codes+scales only)
+    # demoted KV: codes and scales as [layers, max_len, ...] views of the
+    # request's token-major host buffers, cold_len, any ssm state
+    parked: Optional[Dict[str, Any]] = None
     # shadow coverage (§4.5): tokens [0, shadow_pos) of ``parked`` match the
     # device KV bit-for-bit — KV is append-only, so the prefix never goes
     # stale. A preempt at pos == shadow_pos moves zero bytes; at
@@ -198,8 +217,9 @@ def _compiled_fns(cfg: ModelConfig, scfg: ServeConfig, max_len: int):
         return _prefill_impl(params, batch, lens, cfg=cfg, scfg=scfg,
                              max_len=max_len)
 
-    def serve_demote_lane(lane_cache, pos):
-        return _demote_lane_impl(lane_cache, pos, scfg=scfg)
+    def serve_demote_lane(cache, lane, pos):
+        demoted = _demote_lane_impl(_lane_slice(cache, lane), pos, scfg=scfg)
+        return {k: v for k, v in demoted.items() if k not in HOT_KEYS}
 
     def serve_decode(params, cache, tokens, pos, embeds=None):
         return D.decode_step(params, cache, tokens, pos, cfg, scfg, embeds)
@@ -210,38 +230,19 @@ def _compiled_fns(cfg: ModelConfig, scfg: ServeConfig, max_len: int):
 
 # ---------------------------------------------------------------------------
 # Lane slice/install (batch axis 1; hybrid ssm leaves carry a period axis
-# before batch, so the ssm subtree is sliced on its own axis).
+# before batch, so the ssm subtree is sliced on its own axis), and the token
+# ranges a park fetches and a resume installs.
 # ---------------------------------------------------------------------------
 
 def _ssm_batch_axis(cache) -> int:
     return 2 if "k_codes" in cache else 1     # hybrid: [G, period, B, ...]
 
 
-def _lane_slice(cache, lane: int):
+def _lane_slice(cache, lane):
     ax = _ssm_batch_axis(cache)
-    out = {}
-    for k, v in cache.items():
-        if k == "ssm":
-            out[k] = jax.tree_util.tree_map(
-                lambda a: jnp.take(a, lane, axis=ax), v)
-        else:
-            out[k] = v[:, lane]
-    return out
-
-
-def _lane_install(cache, lane: int, lane_cache):
-    ax = _ssm_batch_axis(cache)
-    out = {}
-    for k, v in cache.items():
-        if k == "ssm":
-            out[k] = jax.tree_util.tree_map(
-                lambda a, s: jnp.moveaxis(
-                    jnp.moveaxis(a, ax, 0).at[lane].set(
-                        s.astype(a.dtype)), 0, ax),
-                v, lane_cache[k])
-        else:
-            out[k] = v.at[:, lane].set(lane_cache[k].astype(v.dtype))
-    return out
+    return {k: (jax.tree_util.tree_map(lambda a: jnp.take(a, lane, axis=ax),
+                                       v) if k == "ssm" else v[:, lane])
+            for k, v in cache.items()}
 
 
 def _lanes_install(cache, lanes: jnp.ndarray, sub_cache):
@@ -261,6 +262,95 @@ def _lanes_install(cache, lanes: jnp.ndarray, sub_cache):
     return out
 
 
+def _to_words(x):
+    """``x``'s bytes in row-major order as one vector of 32-bit words. On
+    the device a lane's leaves keep the token axis minor-most and bytes
+    interleaved in tiles, so a range that crosses in its own shape is laid
+    out anew by the host; a word vector's device layout is linear, and it
+    crosses as a plain copy."""
+    if x.dtype != jnp.uint8:
+        x = jax.lax.bitcast_convert_type(x, jnp.uint8)
+    return jax.lax.bitcast_convert_type(x.reshape(-1, 4), jnp.uint32)
+
+
+def _from_words(w, shape, dtype):
+    """The array of ``shape`` and ``dtype`` whose bytes ``_to_words`` gave."""
+    x = jax.lax.bitcast_convert_type(w, jnp.uint8)
+    size = jnp.dtype(dtype).itemsize
+    if size > 1:
+        x = jax.lax.bitcast_convert_type(x.reshape(-1, size), dtype)
+    return x.reshape(shape)
+
+
+def serve_park_range(kept, start, *, n: int):
+    """Positions ``[start, start + n)`` of each token leaf of a demoted lane,
+    token-major (the host buffer's layout) and as words (``_to_words``);
+    other leaves whole. ``dynamic_slice`` clamps a start past
+    ``max_len - n``: the caller passes it clamped already."""
+    return {k: (_to_words(jnp.moveaxis(
+        jax.lax.dynamic_slice_in_dim(v, start, n, 1), 1, 0))
+        if k in TOKEN_KEYS else v) for k, v in kept.items()}
+
+
+def _put_lane(a, lane, x, ax: int = 1):
+    """Write ``x`` (lane ``lane``'s slice of ``a`` without batch axis ``ax``,
+    or a prefix of it along the next axis) into ``a`` at that lane."""
+    start = [0] * a.ndim
+    start[ax] = lane
+    return jax.lax.dynamic_update_slice(
+        a, jnp.expand_dims(x.astype(a.dtype), ax), start)
+
+
+def _prefix(v, words):
+    """A token leaf's parked prefix, from its words, as ``[layers, n, ...]``
+    for the cache leaf ``v`` (``[layers, lanes, max_len, ...]``)."""
+    row = (v.shape[0],) + v.shape[3:]
+    n = words.size * 4 // (int(np.prod(row)) * v.dtype.itemsize)
+    return jnp.moveaxis(_from_words(words, (n,) + row, v.dtype), 0, 1)
+
+
+def serve_install_lane(cache, lane, parked):
+    """Promotion in place: each token leaf's parked prefix (token-major
+    words, ``_to_words``) written over the lane's first ``n`` positions,
+    its hot ring zeroed, its ``cold_len`` and ssm state set. Positions from
+    ``n`` on keep the previous occupant's values, past ``cold_len``."""
+    ax = _ssm_batch_axis(cache)
+
+    def put(a, x, axis=1):
+        return _put_lane(a, lane, x, axis)
+
+    return {k: (jax.tree_util.tree_map(lambda a, s: put(a, s, ax), v,
+                                       parked[k]) if k == "ssm"
+                else put(v, jnp.zeros(v.shape[:1] + v.shape[2:], v.dtype))
+                if k in HOT_KEYS
+                else put(v, _prefix(v, parked[k])) if k in TOKEN_KEYS
+                else put(v, parked[k]))
+            for k, v in cache.items()}
+
+
+# the lane copies depend on shapes alone, so one jitted function each serves
+# every engine; in a profile they are jit_serve_park_range and
+# jit_serve_install_lane
+_park_range_fn = jax.jit(serve_park_range, static_argnames="n")
+_install_fn = jax.jit(serve_install_lane, donate_argnums=0)
+
+
+def _ladder(max_len: int) -> tuple:
+    """Token range lengths a park fetches and a resume uploads: powers of
+    two from ``MIN_RANGE``, then ``max_len``."""
+    out, n = [], MIN_RANGE
+    while n < max_len:
+        out.append(n)
+        n *= 2
+    return tuple(out) + (max_len,)
+
+
+def _token_major(leaf: np.ndarray) -> np.ndarray:
+    """The token-major host buffer behind a parked leaf's
+    ``[layers, max_len, ...]`` view (a view of it, writable)."""
+    return np.moveaxis(leaf, 1, 0)
+
+
 def _leaf_bytes(tree) -> int:
     return sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(tree))
 
@@ -269,9 +359,9 @@ def _moved_bytes(parked: Dict[str, Any], n_tokens: int, max_len: int) -> int:
     """Bytes a park/restore moves in the model: the compressed payload
     (codes + scales) of ``n_tokens`` tokens, plus raw recurrent state for
     ssm/hybrid in full (it has no compressed form and no append-only
-    prefix). The counter is the modeled CXL traffic of the motion; what the
-    full-length host buffers really copy is ``park_fetch_bytes`` and
-    ``resume_upload_bytes``."""
+    prefix). The counter is the modeled CXL traffic of the motion; what
+    really crosses between host and device, token ranges rounded up the
+    ladder, is ``park_fetch_bytes`` and ``resume_upload_bytes``."""
     total = 0
     for k, v in parked.items():
         if k == "ssm":
@@ -310,11 +400,14 @@ class _EngineBase:
                          "step_syncs": 0, "admit_syncs": 0,
                          "shadow_repreempts": 0, "prefill_batches": 0,
                          "cross_expander_resumes": 0,
-                         # bytes that really cross between host and device:
-                         # every leaf a park fetches, every host array a
-                         # resume uploads (preempt_bytes / resume_bytes
-                         # are the modeled CXL payload)
-                         "park_fetch_bytes": 0, "resume_upload_bytes": 0}
+                         # what really crosses between host and device: the
+                         # bytes of the ranges a park fetches and a resume
+                         # uploads (preempt_bytes / resume_bytes are the
+                         # modeled CXL payload), the tokens a park fetches
+                         # (rounded up the ladder), and the parks that
+                         # started past a shadow-covered prefix
+                         "park_fetch_bytes": 0, "resume_upload_bytes": 0,
+                         "park_fetch_tokens": 0, "parked_suffix_fetches": 0}
         # fabric-aware serving: lanes stripe across the expander pool fabric;
         # preempted payloads park on (and are charged to) their lane's
         # expander, and victim selection balances parked load across
@@ -332,6 +425,10 @@ class _EngineBase:
         (self._step_fn, self._prefill_fn, self._demote_fn,
          self._decode_fn) = _compiled_fns(
             cfg, dataclasses.replace(scfg, n_expanders=1), max_len)
+        # ssm models have no token axis: their parks move no range
+        self._ladder = _ladder(max_len) if \
+            any(k in TOKEN_KEYS for k in self.cache) else (0,)
+        self._warm_lane_copies()
 
     # -- client API ---------------------------------------------------------
 
@@ -381,6 +478,22 @@ class _EngineBase:
 
     # -- shared mechanics ---------------------------------------------------
 
+    def _rung(self, need: int) -> int:
+        """The shortest range length of the ladder that holds ``need``
+        tokens (0 where no leaf has a token axis)."""
+        return next((n for n in self._ladder if n >= need), 0)
+
+    def _warm_lane_copies(self) -> None:
+        """Run the demotion and, for every range length of the ladder, the
+        park's range and the resume's install once on lane 0 while the cache
+        is still empty (the install writes back the zeros it read): no
+        length a request reaches later lowers a program while serving."""
+        kept = self._demote_fn(self.cache, np.int32(0), np.int32(0))
+        for n in self._ladder:
+            got = jax.device_get(_park_range_fn(kept, np.int32(0), n=n))
+            self.cache = _install_fn(self.cache, np.int32(0),
+                                     jax.device_put(got))
+
     def _free_lane(self) -> Optional[int]:
         for i, r in enumerate(self.lane_req):
             if r is None:
@@ -396,8 +509,9 @@ class _EngineBase:
 
     def _park_lane(self, req: Request, lane: int) -> None:
         """Demote the lane on device (quantize ring -> codes) and park the
-        compressed payload on the lane's expander, charging only the suffix
-        not already covered by the request's shadow."""
+        compressed payload on the lane's expander: only the positions the
+        request's shadow does not hold cross to the host, and only they are
+        charged."""
         with spans.span(spans.PARK, rid=req.rid, lane=lane, tokens=req.pos):
             covered = req.shadow_pos if req.parked is not None else 0
             exp = int(self.lane_expander[lane])
@@ -405,15 +519,32 @@ class _EngineBase:
                 if req.parked is not None and req.expander >= 0:
                     self.expander_stats["parked"][req.expander] -= 1
                 self.expander_stats["parked"][exp] += 1
+            n = self._rung(req.pos - covered)
+            # a range that would run past max_len starts earlier: the
+            # positions below the shadow are equal on both sides
+            start = min(covered, self.max_len - n)
             with spans.span(spans.PARK_DEMOTE):
-                lane_cache = _lane_slice(self.cache, lane)
-                demoted = self._demote_fn(lane_cache,
-                                          jnp.asarray(req.pos, jnp.int32))
-                kept = {k: v for k, v in demoted.items()
-                        if k not in HOT_KEYS}
+                kept = self._demote_fn(self.cache, np.int32(lane),
+                                       np.int32(req.pos))
+                got = _park_range_fn(kept, np.int32(start), n=n)
             with spans.span(spans.PARK_FETCH):
-                req.parked = self._fetch(kept, "admit_syncs")
-            self.counters["park_fetch_bytes"] += _leaf_bytes(req.parked)
+                got = self._fetch(got, "admit_syncs")
+            self.counters["park_fetch_bytes"] += _leaf_bytes(got)
+            self.counters["park_fetch_tokens"] += n
+            self.counters["parked_suffix_fetches"] += covered > 0
+            if req.parked is None:
+                # one buffer per leaf for the request's life, full length;
+                # pages never written cost nothing and read as zero
+                req.parked = {k: np.moveaxis(np.zeros(
+                    (self.max_len, c.shape[0]) + c.shape[3:], c.dtype), 0, 1)
+                    for k, c in self.cache.items() if k in TOKEN_KEYS}
+            for k, v in got.items():
+                if k in TOKEN_KEYS:
+                    tm = _token_major(req.parked[k])
+                    tm[start:start + n] = v.view(tm.dtype).reshape(
+                        (n,) + tm.shape[1:])
+                else:
+                    req.parked[k] = v
             req.shadow_pos = req.pos
             req.expander = exp
             moved = _moved_bytes(req.parked, req.pos - covered, self.max_len)
@@ -421,19 +552,16 @@ class _EngineBase:
             self.expander_stats["preempt_bytes"][exp] += moved
 
     def _install_parked(self, req: Request, lane: int) -> None:
-        """Promotion: install parked codes into the lane (empty ring, full
+        """Promotion: upload the parked prefix that holds the request's
+        tokens and install it into the lane in place (empty ring, full
         cold_len); no decompression happens (fused attention reads codes
         directly) — zero KV bytes dequantized."""
-        lane_tree = {}
-        for k, a in self.cache.items():
-            if k in HOT_KEYS:
-                lane_tree[k] = jnp.zeros(a.shape[:1] + a.shape[2:], a.dtype)
-            else:
-                lane_tree[k] = jax.tree_util.tree_map(jnp.asarray,
-                                                      req.parked[k])
-                self.counters["resume_upload_bytes"] += \
-                    _leaf_bytes(req.parked[k])
-        self.cache = _lane_install(self.cache, lane, lane_tree)
+        n = self._rung(req.pos)
+        up = {k: (_token_major(a)[:n].reshape(-1).view(np.uint32)
+                  if k in TOKEN_KEYS else a) for k, a in req.parked.items()}
+        self.counters["resume_upload_bytes"] += _leaf_bytes(up)
+        self.cache = _install_fn(self.cache, np.int32(lane),
+                                 jax.device_put(up))
         moved = _moved_bytes(req.parked, req.pos, self.max_len)
         self.counters["resume_bytes"] += moved
         exp = int(self.lane_expander[lane])

@@ -24,7 +24,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro.serve.engine import (DONE, PREEMPTED, RUNNING, Request,
-                                _EngineBase, _lane_install, _lane_slice)
+                                _EngineBase, _lanes_install)
 
 
 class SerialEngine(_EngineBase):
@@ -71,7 +71,7 @@ class SerialEngine(_EngineBase):
             batch["embeds"] = jnp.zeros((1, S, self.cfg.d_model), jnp.bfloat16)
         toks, sub = self._prefill_fn(self.params, batch,
                                      jnp.asarray([S], jnp.int32))
-        self.cache = _lane_install(self.cache, lane, _lane_slice(sub, 0))
+        self.cache = _lanes_install(self.cache, jnp.asarray([lane]), sub)
         self.counters["prefill_batches"] += 1
         tok = int(self._fetch(toks, "admit_syncs")[0])   # a sync per request
         req.generated.append(tok)

@@ -1,9 +1,11 @@
 """Serving-engine integration tests: continuous batching, preemption
 (demotion), resume (promotion), second-chance victim selection, output
 consistency under preemption, the batched scheduler's host-sync contract
-(one sync per decode step), shadowed lane re-preemption (zero bytes), and
-the padded-prefill regression (padded rows must decode identically to
-unpadded ones)."""
+(one sync per decode step), shadowed lane re-preemption (zero bytes), the
+range copies of a park and a resume (the host buffer holds what a fetch of
+the whole demoted lane would; a stale tail past the installed prefix
+changes no served token), and the padded-prefill regression (padded rows
+must decode identically to unpadded ones)."""
 import dataclasses
 
 import jax
@@ -15,7 +17,7 @@ from repro.common.types import ServeConfig
 from repro.configs import get_reduced
 from repro.models import decode as D
 from repro.models import transformer as T
-from repro.serve.engine import Engine, DONE
+from repro.serve.engine import DONE, TOKEN_KEYS, Engine
 from repro.serve.serial import SerialEngine
 
 CFG = get_reduced("llama3_8b")
@@ -168,6 +170,96 @@ def test_shadow_repreempt_moves_zero_bytes(params):
     assert first == per_tok * pos0
     assert 0 < delta < first
     assert delta == 2 * per_tok
+
+
+def _requeue(eng, rid, lane=0):
+    eng.queue.remove(rid)
+    eng.lane_req[lane] = rid
+
+
+@pytest.mark.parametrize("arch", ["llama3_8b", "minicpm3_4b", "zamba2_2p7b"])
+def test_parked_buffer_equals_the_whole_demoted_lane(arch):
+    """GQA, latent (MLA) and hybrid caches: park, resume, decode and re-park
+    one request; at each park the host buffer over [0, pos) is bit for bit
+    the whole demoted lane fetched at once (what a park fetched before it
+    moved ranges), and the leaves without a token axis are that fetch's."""
+    cfg = get_reduced(arch)
+    params = T.init_params(KEY, cfg)[0]
+    scfg1 = ServeConfig(max_running=1, hot_window=16, attn_chunk=32,
+                        kv_rate_bits=8)
+    eng = Engine(cfg, scfg1, params, max_len=128)
+    # 64 tokens: the hybrid's chunked scan takes whole chunks of 32
+    prompt = np.random.default_rng(5).integers(1, cfg.vocab_size, 64)
+    rid = eng.submit(prompt.tolist(), max_new_tokens=40)
+    req = eng.requests[rid]
+    for _ in range(2):
+        eng.step()
+    # parks at 66 (the whole prefix, a range of 128), then 64-token
+    # suffixes from 66, 69 and 72, their starts clamped to 128 - 64
+    for _ in range(4):
+        pos = req.pos
+        whole = jax.device_get(eng._demote_fn(eng.cache, np.int32(0),
+                                              np.int32(pos)))
+        eng._preempt(0)
+        assert req.shadow_pos == pos
+        for k, v in whole.items():
+            if k in TOKEN_KEYS:
+                assert req.parked[k].shape == v.shape
+                assert req.parked[k].dtype == v.dtype
+                np.testing.assert_array_equal(req.parked[k][:, :pos],
+                                              v[:, :pos], err_msg=k)
+            else:
+                jax.tree_util.tree_map(np.testing.assert_array_equal,
+                                       req.parked[k], v)
+        _requeue(eng, rid)
+        eng._resume(req, 0)
+        for _ in range(3):
+            eng.step()
+    assert eng.counters["parked_suffix_fetches"] == 3
+
+
+def test_resume_over_a_longer_occupant_serves_the_same_tokens(params):
+    """A resume installs only the parked prefix: onto a lane whose previous
+    occupant was longer, positions past it keep that occupant's codes. The
+    served tokens, ``cold_len`` and the codes over [0, pos) are those of a
+    resume onto a freshly zeroed lane."""
+    scfg1 = ServeConfig(max_running=1, hot_window=16, attn_chunk=32,
+                        kv_rate_bits=8)
+    runs = []
+    for stale in (True, False):
+        eng = Engine(CFG, scfg1, params, max_len=256)
+        rid = eng.submit(_prompt(11, n=70), max_new_tokens=12)
+        for _ in range(3):
+            eng.step()
+        req = eng.requests[rid]
+        eng._preempt(0)
+        eng.queue.remove(rid)
+        if stale:
+            other = eng.submit(_prompt(12, n=200), max_new_tokens=30)
+            for _ in range(2):
+                eng.step()
+            eng._preempt(0)
+            eng.queue.remove(other)
+            # the resume installs [0, 128): the longer occupant's codes
+            # past it stay
+            assert np.any(np.asarray(eng.cache["k_codes"][:, 0, 128:]) != 0)
+        else:
+            eng.cache = jax.tree_util.tree_map(jnp.zeros_like, eng.cache)
+        eng.lane_req[0] = rid
+        eng._resume(req, 0)
+        pos = req.pos
+        lane = {k: np.asarray(eng.cache[k][:, 0]) for k in
+                ("cold_len", "k_codes", "k_scales", "v_codes", "v_scales")}
+        eng.run_until_done(max_steps=100)
+        assert req.state == DONE
+        runs.append((eng.result(rid), pos, lane))
+    (tok_s, pos, lane_s), (tok_z, _, lane_z) = runs
+    assert tok_s == tok_z
+    np.testing.assert_array_equal(lane_s["cold_len"], pos)
+    for k, v in lane_s.items():
+        np.testing.assert_array_equal(v[:, :pos] if v.ndim > 1 else v,
+                                      lane_z[k][:, :pos] if v.ndim > 1
+                                      else lane_z[k], err_msg=k)
 
 
 def test_padded_prefill_matches_exact(params):

@@ -6,8 +6,11 @@
   * with the profiler on, the engine still syncs once per decode step and
     decodes the same tokens as with it off;
   * ``park_fetch_bytes`` and ``resume_upload_bytes`` count the bytes that
-    really cross: every parked leaf, on a park and on a resume, and nothing
-    on a re-preempt the shadow covers;
+    really cross: a park's token range past the shadow and a resume's
+    prefix, each rounded up the ladder of range lengths, plus ``cold_len``;
+    nothing on a re-preempt the shadow covers;
+  * a built engine parks and resumes at every range length without
+    lowering a program;
   * the replay window program carries the four phase scopes, and is the
     same program without them.
 """
@@ -29,7 +32,7 @@ from repro.core.engine import state as S
 from repro.core.engine.policy import POLICIES
 from repro.models import transformer as T
 from repro.obs import spans
-from repro.serve.engine import Engine
+from repro.serve.engine import TOKEN_KEYS, Engine
 
 CFG = get_reduced("llama3_8b")
 SCFG = ServeConfig(max_running=2, hot_window=16, attn_chunk=32,
@@ -123,17 +126,23 @@ def test_profiled_engine_syncs_once_per_step_and_decodes_the_same(
                          eng_on.counters["step_syncs"], what="profiled")
 
 
-def _leaves_nbytes(parked):
-    return sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(parked))
+def _per_token_and_whole(eng):
+    """Bytes of one token of a lane's token leaves, and of its ``cold_len``
+    (the leaves a park moves whole)."""
+    lanes = eng.lanes
+    per_tok = sum(int(eng.cache[k].nbytes) // (lanes * eng.max_len)
+                  for k in TOKEN_KEYS if k in eng.cache)
+    return per_tok, int(eng.cache["cold_len"].nbytes) // lanes
 
 
 def test_park_and_resume_count_the_bytes_that_cross(params):
-    eng = Engine(CFG, SCFG, params, max_len=128)
-    rid = eng.submit(_prompt(0), 10)
+    eng = Engine(CFG, SCFG, params, max_len=128)     # ranges of 64 and 128
+    rid = eng.submit(_prompt(0, n=80), 10)
     for _ in range(3):
         eng.step()
     req = eng.requests[rid]
     c = eng.counters
+    per_tok, whole = _per_token_and_whole(eng)
 
     def delta(before):
         return (c["park_fetch_bytes"] - before[0],
@@ -146,19 +155,74 @@ def test_park_and_resume_count_the_bytes_that_cross(params):
         eng.queue.remove(rid)
         eng.lane_req[0] = rid
 
+    assert 64 < req.pos <= 128
     m = mark()
-    eng._preempt(0)                                   # park
-    whole = _leaves_nbytes(req.parked)
-    assert whole > 0 and delta(m) == (whole, 0)
-    # every position of every leaf crosses, not only the parked tokens
-    assert whole > c["preempt_bytes"]
+    eng._preempt(0)               # first park: [0, pos) rounded up to 128
+    assert delta(m) == (128 * per_tok + whole, 0)
+    assert c["park_fetch_tokens"] == 128 and c["parked_suffix_fetches"] == 0
     m = mark()
     requeue()
-    eng._resume(req, 0)                               # resume
-    assert delta(m) == (0, whole)
+    eng._resume(req, 0)           # resume: the prefix [0, 128)
+    assert delta(m) == (0, 128 * per_tok + whole)
     m = mark()
-    eng._preempt(0)                                   # shadow hit
+    eng._preempt(0)               # shadow hit
     assert c["shadow_repreempts"] == 1 and delta(m) == (0, 0)
+    requeue()
+    eng._resume(req, 0)
+    eng.step()
+    eng.step()
+    m = mark()
+    eng._preempt(0)               # a 2-token suffix: a range of 64
+    assert delta(m) == (64 * per_tok + whole, 0)
+    assert c["park_fetch_tokens"] == 128 + 64
+    assert c["parked_suffix_fetches"] == 1
+    # the logical charge is the suffix alone
+    assert c["preempt_bytes"] == (req.pos) * per_tok
+
+
+def test_parks_and_resumes_across_the_ladder_lower_no_program(params):
+    """An engine compiles the park's and the resume's programs of every
+    range length when it is built: once serving has warmed its own shapes,
+    parks and resumes at range lengths it has not used yet lower nothing
+    (the benchmark refuses a window that lowers a program)."""
+    from jax._src import dispatch
+    eng = Engine(CFG, SCFG, params, max_len=256)   # ranges 64, 128, 256
+    short = eng.submit(_prompt(1, n=20), 40)
+    long_ = eng.submit(_prompt(2, n=150), 40)
+    for _ in range(2):
+        eng.step()
+    lane = {r: eng.requests[r].lane for r in (short, long_)}
+
+    def cycle(rid):
+        eng._preempt(lane[rid])
+        eng.queue.remove(rid)
+        eng.lane_req[lane[rid]] = rid
+        eng._resume(eng.requests[rid], lane[rid])
+
+    cycle(short)                   # the first park and resume: 64 tokens
+    eng.step()
+    events = {dispatch.JAXPR_TO_MLIR_MODULE_EVENT,
+              dispatch.BACKEND_COMPILE_EVENT}
+    lowered = []
+
+    def listen(event, duration, **_):
+        if event in events:
+            lowered.append(event)
+
+    jax.monitoring.register_event_duration_secs_listener(listen)
+    try:
+        cycle(long_)               # the first park of 152 tokens: 256
+        cycle(long_)               # a shadow hit: nothing
+        eng.step()
+        eng.step()
+        cycle(long_)               # a suffix: 64
+        cycle(short)               # a suffix: 64
+    finally:
+        jax.monitoring.unregister_event_duration_listener(listen)
+    c = eng.counters
+    assert c["park_fetch_tokens"] == 64 + 256 + 64 + 64
+    assert c["shadow_repreempts"] == 1 and c["parked_suffix_fetches"] == 2
+    assert lowered == []
 
 
 def _pool_case():
