@@ -225,7 +225,8 @@ def phase_replay(args):
 
 def _fabric_setup(n_exp: int, pages: int, accesses: int, seed: int,
                   window: int):
-    """The weighted-skew spill fabric of benchmarks/fabric_sharded.py."""
+    """A weighted-skew spill fabric: expander 0 takes most pages, so the
+    spill path fires."""
     import jax.numpy as jnp
     from repro.common.types import replace
     from repro.core.engine.policy import POLICIES
@@ -276,7 +277,7 @@ def phase_pool(args):
     from repro.core import engine as E
     from repro.core.compressor import resolve_impl
     pol = E.DEFAULT_POLICY
-    # benchmarks/system_bench.py's Layer-A shape
+    # a test-sized Layer-A pool with payloads stored
     cfg_k = PoolConfig(n_pages=64, n_cchunks=512, n_pchunks=32,
                        mcache_sets=4, mcache_ways=4, demote_watermark=4,
                        store_payload=True)

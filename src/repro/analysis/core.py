@@ -32,7 +32,7 @@ runtime stack. Three layers:
 The walk is deliberately conservative in BOTH directions: unknown names
 (imports, closures from non-region scopes) are untainted — a false
 positive costs developer trust, a false negative is caught by the
-runtime sync counters the benches already assert — and every finding is
+runtime sync counters the tests already assert — and every finding is
 suppressible inline with ``# lint: host-ok(reason)``.
 """
 from __future__ import annotations

@@ -1,8 +1,8 @@
 """R5 — declared host-sync contracts (``@sync_contract``).
 
 The static half of ``repro.common.contracts`` (the runtime half is
-``verify_sync_counters`` in the benches). For every function annotated
-``@sync_contract(syncs_per=..., fetches=N)``:
+``verify_sync_counters`` in tests/test_obs.py and test_spans.py). For
+every function annotated ``@sync_contract(syncs_per=..., fetches=N)``:
 
   * count the lexical device→host *fetch sites* in the body —
     ``jax.device_get``, ``.item()``, ``.block_until_ready()``,

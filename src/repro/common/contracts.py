@@ -3,7 +3,7 @@
 Every speedup layer in this repro rests on a host-sync discipline — ONE
 device→host sync per decode step (serve), per replayed segment and per
 migration epoch (fabric). Until now those contracts lived in docstrings
-and were enforced only at runtime by benches a regressing PR may not run.
+and were enforced only at runtime by harnesses a regressing PR may not run.
 ``@sync_contract`` turns them into annotations that are checked twice:
 
   * **statically** — ``repro.analysis`` rule R5 counts the device→host
@@ -15,7 +15,7 @@ and were enforced only at runtime by benches a regressing PR may not run.
     O(n));
   * **at runtime** — ``verify_sync_counters`` cross-checks the measured
     sync counters (``step_syncs == steps``, ``segment_syncs ==
-    segments``, ...) against the declared budget, so the benches assert
+    segments``, ...) against the declared budget, so the tests assert
     the *declared* contract rather than a magic constant of their own.
 
 The decorator is intentionally a no-op at call time (it only attaches a
@@ -34,7 +34,7 @@ _ATTR = "__sync_contract__"
 
 # The event kinds the repo's runtime counters are keyed on. Free-form
 # strings are allowed (the analyzer only needs identity), but sticking to
-# these keeps the bench cross-checks uniform. "boundary" is the sharded
+# these keeps the runtime cross-checks uniform. "boundary" is the sharded
 # fabric driver's fused per-segment-boundary fetch, "drain" its one
 # deferred migration-off fetch per replay() call, "call" a per-invocation
 # metric fetch (Fabric.delivered_time).

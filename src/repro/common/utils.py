@@ -1,8 +1,7 @@
-"""Small shared utilities: bit manipulation, tree helpers, timing."""
+"""Small shared utilities: bit manipulation, tree helpers."""
 from __future__ import annotations
 
-import time
-from typing import Any, Callable, Sequence
+from typing import Any
 
 import jax
 import jax.numpy as jnp
@@ -107,33 +106,3 @@ def cdiv(a: int, b: int) -> int:
 
 def round_up(a: int, b: int) -> int:
     return cdiv(a, b) * b
-
-
-class Timer:
-    """Wall-clock timer for benchmark harness (block until ready)."""
-
-    def __init__(self) -> None:
-        self.t0 = 0.0
-
-    def __enter__(self) -> "Timer":
-        self.t0 = time.perf_counter()
-        return self
-
-    def __exit__(self, *exc: Any) -> None:
-        self.elapsed = time.perf_counter() - self.t0
-
-
-def time_fn(fn: Callable[[], Any], iters: int = 5, warmup: int = 1) -> float:
-    """Median microseconds per call; blocks on all returned arrays."""
-    def run() -> None:
-        out = fn()
-        jax.tree_util.tree_map(
-            lambda x: x.block_until_ready() if hasattr(x, "block_until_ready") else x, out)
-    for _ in range(warmup):
-        run()
-    times = []
-    for _ in range(iters):
-        t0 = time.perf_counter()
-        run()
-        times.append((time.perf_counter() - t0) * 1e6)
-    return float(np.median(times))

@@ -284,7 +284,7 @@ def _serial_access(pool: Pool, cfg: PoolConfig, policy: Policy, ospn, w, blk,
 def _replay_serial(pool: Pool, cfg: PoolConfig, policy: Policy, ospns,
                    writes, blocks, valid=None) -> Pool:
     """The seed's one-access-per-step scan (kept as the batched path's
-    reference and for BENCH_simx.json before/after measurements).
+    reference: ``replay_trace(window=1)`` runs it).
 
     ``valid=None`` processes every access. A bool mask makes masked-out
     accesses exact no-ops (pool and counters untouched) — the batched path
@@ -319,8 +319,7 @@ def _replay_windows_masked(pool: Pool, cfg: PoolConfig, policy: Policy,
     and its counters are bit-identical to an unpadded `replay_trace` of the
     real prefix (asserted by tests/test_fabric.py). Under `vmap` the
     three-way branch lowers to selects, so every expander pays the heavier
-    body's cost; fabric throughput numbers carry that constant honestly
-    (benchmarks/fabric_bench.py).
+    body's cost; fabric throughput numbers carry that constant honestly.
 
     ``unroll_slow`` is forwarded to ``_window_step``: the sharded fabric
     passes True because XLA:CPU miscompiles the fori/while slow-access

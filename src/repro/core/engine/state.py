@@ -148,7 +148,7 @@ def stacked_counters(stack: Pool) -> jnp.ndarray:
 def stacked_counters_dict(stack: Pool) -> dict:
     """Aggregate counters of a stacked pool state, same keys as
     ``counters_dict`` — per-expander traffic sums are the fabric's parity
-    contract with single-pool replay (benchmarks/fabric_bench.py)."""
+    contract with single-pool replay (tests/test_fabric.py)."""
     vals = [int(v) for v in stacked_counters(stack)]
     return dict(zip(COUNTER_NAMES, vals))
 

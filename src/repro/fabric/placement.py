@@ -11,7 +11,7 @@ A ``Placement`` owns the page->expander map the fabric routes with:
   * ``LocalityAffinity``  — contiguous OSPN ranges per expander (NUMA-style
     affinity: pages of one tenant/zone land together);
   * ``WeightedInterleave`` — hash interleave with per-expander weights; the
-    skew knob for the fabric bench's sensitivity sweep.
+    skew knob (``launch/fabric.py --skew``) that starves one expander.
 
 All placements carry an *override* table written by the spill/migration
 path (fabric/ops.py): once a page migrates, routing follows the override,

@@ -8,7 +8,7 @@ a common window-aligned length, and replayed through
 the window bodies are the single-pool ones, unchanged, so per-expander
 counters are bit-identical to replaying that expander's partition through
 ``batch.replay_trace`` on a single pool (the fabric's parity contract,
-asserted by tests/test_fabric.py and benchmarks/fabric_bench.py).
+asserted by tests/test_fabric.py).
 
 The replay advances in *segments* (``spill_interval`` accesses per
 expander, window-aligned). Each segment is one pipeline stage:
@@ -36,7 +36,7 @@ degenerates to plan-and-apply at the same boundary and is bit-identical
 to the synchronous reference driver (``sync_migration=True``, the PR 3
 semantics: migration on the critical path) — the refactor's parity pin.
 
-Host-sync contract (machine-checked by benchmarks/fabric_bench.py,
+Host-sync contract (asserted by tests/test_fabric.py and test_obs.py,
 mirroring serve's ``step_syncs == steps``): exactly ONE host sync per
 replayed segment (the fused stats fetch) plus ONE per committed
 migration epoch (the moved-pages fetch) — no per-page host writes, no
@@ -760,8 +760,8 @@ class Fabric:
         stores land in those pools' counters).
 
         ``exact=True`` (default, host-side) recomputes in float64 through
-        the same ``exec_time_vec`` — the parity-grade numbers benches
-        record. ``exact=False`` returns the float32 values the vmapped
+        the same ``exec_time_vec`` — the parity-grade numbers the tests
+        pin. ``exact=False`` returns the float32 values the vmapped
         replay computed on device (zero extra device work) — or, when a
         trailing migration invalidated them, re-prices the current
         counters through the same float32 device path, never the float64
@@ -877,7 +877,8 @@ class Fabric:
         """Bit-identity of two fabrics' end states: every leaf of the
         stacked pool pytree (so counters included), plus the placement
         override tables. THE parity predicate — the depth-1-vs-sync pin
-        in tests, bench, and the CI smoke all call this one definition."""
+        in tests, the launcher and the CI smoke all call this one
+        definition."""
         pools_equal = jax.tree_util.tree_all(jax.tree_util.tree_map(
             lambda a, b: bool(jnp.array_equal(a, b)), self.pools,
             other.pools))
@@ -897,7 +898,7 @@ class Fabric:
         }
 
     def sync_stats(self) -> Dict[str, int]:
-        """The host-sync contract (asserted by benchmarks/fabric_bench.py):
+        """The host-sync contract (asserted by tests/test_fabric.py):
         on the vmap drivers one fused stats fetch per replayed segment
         plus one moved-pages fetch per committed migration epoch; on the
         sharded driver one fused fetch per boundary (migration on) or

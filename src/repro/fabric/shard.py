@@ -8,8 +8,7 @@ does not. This module runs the same computation ``shard_map``-ed over the
 owns an equal block of ``L = N / D`` expanders and replays its shard with
 the SAME vmapped ``batch._replay_windows_masked`` window bodies, so
 per-expander counters are bit-identical to the single-device vmap oracle
-(all pool state is integer; asserted by tests/test_fabric_sharded.py and
-every benchmarks/fabric_bench.py sharded point).
+(all pool state is integer; asserted by tests/test_fabric_sharded.py).
 
 Three pieces:
 

@@ -115,25 +115,21 @@ def main() -> None:
                          "sibling, and print the per-segment summary table")
     ap.add_argument("--device-profile", default="default",
                     help="comma-separated simx.time.DEVICE_PROFILES names "
-                         f"({', '.join(sorted(TM.DEVICE_PROFILES))}) or "
-                         "'calibrated' (engine constants from the measured "
-                         "BENCH_kernels.json; paper constants if absent), "
+                         f"({', '.join(sorted(TM.DEVICE_PROFILES))}), "
                          "cycled across expanders — e.g. 'default,gen4' "
                          "makes an alternating mixed-generation fleet")
     args = ap.parse_args()
     enable_compile_cache()
 
     profiles = [p.strip() for p in args.device_profile.split(",") if p.strip()]
-    unknown = [p for p in profiles
-               if p != "calibrated" and p not in TM.DEVICE_PROFILES]
+    unknown = [p for p in profiles if p not in TM.DEVICE_PROFILES]
     if unknown:
         ap.error(f"unknown device profile(s) {unknown}; choose from "
-                 f"{sorted(TM.DEVICE_PROFILES) + ['calibrated']}")
+                 f"{sorted(TM.DEVICE_PROFILES)}")
     if len(profiles) > args.expanders:
         ap.error(f"{len(profiles)} device profiles for "
                  f"{args.expanders} expanders")
-    devices = [TM.calibrated_device() if p == "calibrated"
-               else TM.DEVICE_PROFILES[p] for p in profiles]
+    devices = [TM.DEVICE_PROFILES[p] for p in profiles]
 
     policy = POLICIES[args.scheme]
     cfg = pool_cfg_for(policy, n_pages=args.pages, n_pchunks=args.prom,

@@ -16,8 +16,7 @@ Two parts, one per form of the program:
   ``state.COUNTER_NAMES`` — never integer literals, R3 stays clean) and a
   structured event log; exporters turn them into a Chrome/Perfetto
   ``trace_event`` timeline of the *modeled* pipeline pricing, a
-  ``metrics.json`` snapshot, and the run manifest stamped into every
-  BENCH_*.json.
+  ``metrics.json`` snapshot, and the run manifest stamped into both.
 
 Recording is opt-in: ``obs=None`` (the default everywhere) is the
 recording-off path, bit-identical in pool/counter state to recording-on

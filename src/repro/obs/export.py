@@ -7,8 +7,8 @@ span it hid behind, and each track's cursor advances by
 ``max(replay, migration)`` per row — so a track's total extent equals
 ``Fabric.pipeline_times()["overlapped_s"]`` for that expander exactly
 (``fabric_track_totals`` calls the same ``pipeline_delivered_time`` on
-the same row matrices; benchmarks/fabric_bench.py asserts the
-reconciliation). Urgent/sync/drain epochs get their own zero-replay rows,
+the same row matrices; tests/test_obs.py asserts the reconciliation).
+Urgent/sync/drain epochs get their own zero-replay rows,
 charged in full on the critical path, exactly as ``pipeline_times``
 prices them.
 
@@ -30,7 +30,7 @@ per-track monotone timestamps, and proper span nesting (overlapping spans
 on one track must nest).
 
 jax and the timing model are imported lazily — ``repro.obs`` stays
-importable on jax-free hosts (manifest stamping from the lint bench).
+importable on jax-free hosts, where ``manifest()`` runs too.
 """
 from __future__ import annotations
 
@@ -252,7 +252,7 @@ def write_trace(rec: Recorder, path) -> Dict[str, Any]:
 
 def metrics_snapshot(rec: Recorder, **meta: Any) -> Dict[str, Any]:
     """The ``metrics.json`` payload: manifest + registry snapshot + the
-    per-domain roll-ups benches fold into BENCH_*.json."""
+    per-domain roll-ups (fabric placement, pipeline pricing)."""
     out: Dict[str, Any] = {"manifest": manifest(**meta),
                            "metrics": rec.metrics.snapshot()}
     if rec.fabric_info is not None:

@@ -1,14 +1,13 @@
 """Run manifest: the one description of "what produced this artifact".
 
-Every BENCH_*.json writer, the launch ``--trace`` exports and the
-``metrics.json`` snapshot stamp the same dict, built here — previously
-each bench hand-rolled its own ``meta`` and they had drifted on which
-fields they carried. Keys: backend + device count, jax/jaxlib versions,
-python/platform, seed, git sha.
+The launch ``--trace`` exports (the Perfetto trace and its
+``metrics.json`` snapshot) stamp the same dict, built here. Keys:
+backend + device count, jax/jaxlib versions, python/platform, seed, git
+sha.
 
-Must import (and run) on jax-free hosts — the lint bench and analysis
-tooling stamp manifests too — so the jax block is best-effort: missing
-accelerator stack degrades to ``backend: None``, never an ImportError.
+Must import (and run) on jax-free hosts, so the jax block is
+best-effort: a missing accelerator stack degrades to ``backend: None``,
+never an ImportError.
 """
 from __future__ import annotations
 
@@ -31,10 +30,10 @@ def _git_sha() -> Optional[str]:
 
 
 def manifest(seed: Optional[int] = None, **extra: Any) -> Dict[str, Any]:
-    """The run manifest stamped into every BENCH meta and obs export.
+    """The run manifest stamped into every obs export.
 
     ``seed`` is recorded when the producing run has one; ``extra``
-    key/values ride along verbatim (a bench's own knobs — sizes, point
+    key/values ride along verbatim (a run's own knobs — sizes, point
     names — belong in its results, not here)."""
     out: Dict[str, Any] = {
         "python": platform.python_version(),

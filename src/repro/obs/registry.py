@@ -3,7 +3,7 @@
 Pure stdlib + numpy-free on purpose — the registry is host-side
 bookkeeping fed by the Recorder's piggyback drains, and it must stay
 importable (like ``repro.analysis``) on machines with no accelerator
-stack. Metric *names* are the single namespace benches and exporters
+stack. Metric *names* are the single namespace tests and exporters
 key on; counter metrics derived from the pool's traffic vector are keyed
 by ``state.COUNTER_NAMES`` / ``state.TRAFFIC_NAMES`` entries, never by
 integer position — the R3 layout-drift rule stays clean by construction.
@@ -119,7 +119,7 @@ def merge_histograms(hists: Sequence[Histogram]) -> Optional[Histogram]:
 
 class MetricsRegistry:
     """Get-or-create registry: one flat name → metric namespace. The
-    Recorder is the only writer on the hot path; benches and exporters
+    Recorder is the only writer on the hot path; tests and exporters
     read ``snapshot()``."""
 
     def __init__(self):

@@ -5,8 +5,8 @@
 qpack compressor applied to training substrate. HBM for optimizer state drops
 from 8 bytes/param (2xf32) to ~2.06 bytes/param, exactly the capacity-
 expansion story of the paper turned onto the training side. Error behaves
-like stochastic-rounding noise on the moments; wall-clock cost is two extra
-qpack codec passes per step (measured in benchmarks/state_compression.py).
+like stochastic-rounding noise on the moments; the cost is two extra qpack
+codec passes per step (not measured).
 """
 from __future__ import annotations
 

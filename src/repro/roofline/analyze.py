@@ -160,27 +160,3 @@ def analyze_record(rec: Dict, tokens: int, kind: str) -> Optional[Roofline]:
         collective=rec["collective_bytes"], chips=chips,
         params=rec["params"], active_params=rec["active_params"],
         tokens=tokens, kind=kind)
-
-
-def kernel_roofline(rows: List[Dict], hbm_bw: float = HBM_BW) -> List[Dict]:
-    """Distance-from-bandwidth-bound for measured kernel rows (the qpack
-    encode/decode/fused-demote kernels are pure streaming: ~0 FLOPs/byte,
-    so the HBM roof *is* their speed-of-light). Each input row needs
-    ``name``, ``bytes`` (uncompressed bytes moved per call) and ``us``
-    (median wall time); emits GB/s, fraction of the HBM roof, and the
-    bound classification used by BENCH_kernels.json."""
-    out = []
-    for r in rows:
-        us = float(r.get("us", 0.0))
-        nbytes = float(r.get("bytes", 0.0))
-        if us <= 0 or nbytes <= 0:
-            continue
-        gbps = nbytes / (us * 1e-6) / 1e9
-        frac = gbps * 1e9 / hbm_bw
-        out.append({
-            "name": r["name"],
-            "gbps": gbps,
-            "frac_of_hbm_roof": frac,
-            "bound": "bandwidth" if frac >= 0.5 else "overhead",
-        })
-    return out

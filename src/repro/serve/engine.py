@@ -59,14 +59,14 @@ then split into calls of at most that many tokens, so that long prompts do
 not cap the lane count at (all lanes x the largest bucket).
 
 Scheduling: FIFO admission, at most one preemption per engine step. All
-cache motion is counted in ``self.counters`` (bytes and events) for
-benchmarks/serve_bench.py: parked bytes are the *compressed* payload
+cache motion is counted in ``self.counters`` (bytes and events): parked
+bytes are the *compressed* payload
 (codes + scales) of the moved tokens — the bf16 hot ring is quantized
 before parking, never moved raw.
 
 ``serve.serial.SerialEngine`` keeps the per-lane host-loop implementation
 (per-request prefill, one sync per lane per step, no shadow) as the
-benchmark baseline; both engines share ``_EngineBase``.
+tests' scheduling oracle; both engines share ``_EngineBase``.
 """
 from __future__ import annotations
 
@@ -735,7 +735,7 @@ class Engine(_EngineBase):
         suffix (_park_lane). The zero-byte branch is the N=0 limit of the
         suffix charge — in this engine's own loop a resumed lane always
         decodes before it can be re-selected, so the limit case fires only
-        when a caller (scheduler churn, tests, serve_bench) preempts between
+        when a caller (scheduler churn, tests) preempts between
         resume and decode; the organic payoff is the suffix-only charge."""
         rid = self.lane_req[lane]
         req = self.requests[rid]
@@ -765,7 +765,7 @@ class Engine(_EngineBase):
     def step(self) -> bool:
         """One engine iteration. Returns False when no work remains.
         Exactly one host sync per call once lanes are running — declared
-        above and checked both by the R5 lint and by the benches via
+        above and checked both by the R5 lint and by tests/test_spans.py via
         ``verify_sync_counters`` (step_syncs == steps)."""
         with spans.span(spans.STEP, step=self.counters["steps"]):
             with spans.span(spans.ADMIT):

@@ -1,13 +1,12 @@
-"""Per-lane reference serving engine — the batched scheduler's baseline.
+"""Per-lane reference serving engine — the batched scheduler's oracle.
 
 This is the pre-batching engine shape, kept deliberately: one prefill
 compile+sync per request (no length bucketing), per-lane Python loops in
 ``step`` with a full-logits fetch every step, and no lane shadowing (resume
 drops the parked copy, so every re-preempt pays the full demotion again).
-``benchmarks/serve_bench.py`` serves the same workload through this and
-through ``serve.engine.Engine`` and records the tokens/sec and preempt-bytes
-gap; tests use it as a semantics reference (same model, same decode step —
-generations must match).
+Tests use it as the scheduling oracle (same model, same decode step —
+``test_batched_engine_matches_serial_engine`` requires the generations to
+match), and ``launch/serve.py --serial`` serves through it.
 
 The correctness fixes are shared with the batched engine (via
 ``_EngineBase``): prompts are prefilled at their exact length (no

@@ -1,2 +1,2 @@
 """Layer B: trace-driven reproduction of the paper's SST evaluation."""
-from repro.simx import device, engine, time, trace  # noqa: F401
+from repro.simx import engine, time, trace  # noqa: F401

@@ -1,6 +1,7 @@
 """Trace-driven evaluation engine: runs a workload trace through the pool
 engine (payload-less) under each compared scheme, reproducing the paper's
-SST-based methodology as traffic counts + the device.py time model.
+SST-based methodology as traffic counts + the simx.time delivered-time
+model.
 
 Schemes are first-class ``Policy`` modules (repro.core.engine.policy): each
 scheme's extra traffic — TMCC's LRU-list updates and zsmalloc bookkeeping,
@@ -9,8 +10,7 @@ charged by policy hooks at the access site where it physically occurs; there
 are no post-hoc counter adjustments. Traces replay through the batched
 front-end (repro.core.engine.batch): a window of W accesses per scan step
 with vectorized fast-path accounting, which is what makes the full workload
-sweep CPU-tractable (before/after accesses/sec are tracked in
-BENCH_simx.json).
+sweep CPU-tractable.
 
 Compresso (line-level, no promotion machinery) keeps its dedicated model.
 """
@@ -26,7 +26,6 @@ from repro.common.types import PoolConfig, replace
 from repro.core.engine import batch as B
 from repro.core.engine import state as S
 from repro.core.engine.policy import POLICIES, Policy
-from repro.simx import device as DEV
 from repro.simx import time as TM
 from repro.simx.trace import WorkloadSpec, make_rates_table, make_trace
 
@@ -71,7 +70,7 @@ def first_touch_populate(pool, cfg: PoolConfig, policy: Policy, *,
 def run_workload(scheme_name: str, spec: WorkloadSpec, *,
                  n_accesses: int = 20000, promoted_pages: int = 128,
                  seed: int = 0, first_touch: bool = True,
-                 device: Optional[DEV.DeviceConfig] = None,
+                 device: Optional[TM.DeviceConfig] = None,
                  window: int = DEFAULT_WINDOW, obs=None) -> Dict[str, float]:
     """Run one (scheme x workload) cell; returns traffic + time metrics.
 
@@ -87,7 +86,7 @@ def run_workload(scheme_name: str, spec: WorkloadSpec, *,
     rates = make_rates_table(spec, n_pages, seed=seed)
     ospn, is_write, block = make_trace(spec, n_accesses=n_accesses,
                                        n_pages=n_used, seed=seed)
-    dev = device or DEV.DeviceConfig()
+    dev = device or TM.DeviceConfig()
     if policy.block4k_engine:
         dev = replace(dev, block_scale=4.0)
 
@@ -111,12 +110,12 @@ def run_workload(scheme_name: str, spec: WorkloadSpec, *,
     return out
 
 
-def _finalize(c: Dict[str, int], dev: DEV.DeviceConfig, ratio: float
+def _finalize(c: Dict[str, int], dev: TM.DeviceConfig, ratio: float
               ) -> Dict[str, float]:
     """Assemble the metrics dict. All scheme-specific traffic was already
     counted in place by policy hooks — nothing is adjusted here. Time comes
     from the vectorized model over the counter vector (float64 host path —
-    bitwise what the legacy dict shim computes)."""
+    bitwise what ``time.exec_time_dict`` computes)."""
     t = {k: float(c[k]) for k in TRAFFIC_KEYS}
     internal = sum(t.values())
     traffic = dict(t, internal_accesses=internal,
@@ -137,7 +136,7 @@ def _finalize(c: Dict[str, int], dev: DEV.DeviceConfig, ratio: float
 
 
 def _run_compresso(spec: WorkloadSpec, rates: np.ndarray, ospn: np.ndarray,
-                   is_write: np.ndarray, dev: DEV.DeviceConfig
+                   is_write: np.ndarray, dev: TM.DeviceConfig
                    ) -> Dict[str, float]:
     """Line-level compression (no promotion machinery): metadata access on
     mcache miss; ~1.05 data accesses per read (lines pack across 64B), ~2.2

@@ -13,8 +13,8 @@ post-hoc script:
   * ``exec_time_vec`` — the vectorized model: operates on counter *arrays*
     in ``engine.state.COUNTER_NAMES`` order (the ``Pool.counters`` vector),
     broadcasting over any leading axes, under ``jnp`` (inside jit/vmap) or
-    ``np`` (host-side float64). The legacy string-keyed-dict API survives as
-    ``exec_time_dict`` — a thin shim over the same core, bitwise-identical
+    ``np`` (host-side float64). The string-keyed-dict API is
+    ``exec_time_dict`` — a thin face over the same core, bitwise-identical
     to the old scalar model (tests/test_time_model.py pins this).
 
 Model (documented approximation, not cycle-accurate): execution time is the
@@ -36,8 +36,6 @@ sync costs one CXL round trip.
 from __future__ import annotations
 
 import dataclasses
-import json
-import pathlib
 from dataclasses import dataclass
 from typing import Dict, List, Mapping, NamedTuple, Sequence, Union
 
@@ -70,9 +68,10 @@ def ideal_bandwidth(dev: DeviceConfig) -> DeviceConfig:
 
 
 # Named generation profiles for mixed fleets (launch/fabric.py
-# --device-profile, benchmarks/fabric_bench.py mixed-fleet rows). "gen4" is
-# a previous-generation expander (PCIe4 x8 link, DDR4-ish channels, slower
-# engine clock); "far" sits behind a CXL switch (latency only).
+# --device-profile, test_fabric.py's mixed-fleet delivered-time test).
+# "gen4" is a previous-generation expander (PCIe4 x8 link, DDR4-ish
+# channels, slower engine clock); "far" sits behind a CXL switch (latency
+# only).
 DEVICE_PROFILES: Dict[str, DeviceConfig] = {
     "default": DeviceConfig(),
     "gen4": DeviceConfig(ch_bw=25.6e9, cxl_bw=16e9, cxl_lat=110e-9,
@@ -81,41 +80,6 @@ DEVICE_PROFILES: Dict[str, DeviceConfig] = {
     "slow_engine": DeviceConfig(clock=1.0e9, comp_cycles=512,
                                 decomp_cycles=128),
 }
-
-# Default location of the measured-kernel bench artifact (repo root; written
-# by benchmarks/kernel_bench.py).
-_BENCH_KERNELS = pathlib.Path(__file__).resolve().parents[3] / "BENCH_kernels.json"
-
-
-def calibrated_device(path: "str | pathlib.Path | None" = None,
-                      base: "DeviceConfig | None" = None) -> DeviceConfig:
-    """DeviceConfig whose compression-engine constants are derived from the
-    measured kernel throughput in ``BENCH_kernels.json`` instead of the
-    paper's assumed 256/64 cycles per block.
-
-    cycles/block = clock * block_bytes / measured_bytes_per_second, i.e. the
-    engine is modeled at exactly the GB/s the fused demote/promote kernels
-    sustained on this host (benchmarks/kernel_bench.py 'calibration'
-    section). Falls back to the paper constants (``base``) when the bench
-    file is missing or lacks the calibration section, so delivered-time
-    behavior never silently depends on an uncommitted artifact."""
-    base = base if base is not None else DeviceConfig()
-    p = pathlib.Path(path) if path is not None else _BENCH_KERNELS
-    try:
-        data = json.loads(p.read_text())
-    except (OSError, ValueError):
-        return base
-    cal = data.get("calibration", {})
-    comp_gbps = cal.get("compress_gbps")
-    decomp_gbps = cal.get("decompress_gbps")
-    if not comp_gbps or not decomp_gbps:
-        return base
-    blk = float(cal.get("block_bytes", 1024))
-    comp_cycles = max(1, int(round(base.clock * blk / (comp_gbps * 1e9))))
-    decomp_cycles = max(1, int(round(base.clock * blk / (decomp_gbps * 1e9))))
-    return dataclasses.replace(base, comp_cycles=comp_cycles,
-                               decomp_cycles=decomp_cycles)
-
 
 class DeviceLanes(NamedTuple):
     """A stacked expander fleet: ``DeviceConfig`` field-for-field, each a
@@ -172,7 +136,7 @@ def resolve_fleet(devices, n_expanders: int) -> List[DeviceConfig]:
 
 # ---------------------------------------------------------------------------
 # The model core. One implementation serves every caller: python scalars
-# (legacy dict shim, float64), numpy arrays (host-side sweeps, float64), and
+# (``exec_time_dict``, float64), numpy arrays (host-side sweeps, float64), and
 # jnp arrays inside jit/vmap (fabric replay, float32). Operation order is
 # EXACTLY the legacy scalar model's, so the float64 paths are bitwise
 # identical to the pre-refactor code.
@@ -230,7 +194,7 @@ def counters_from_dict(traffic: Mapping[str, float]) -> np.ndarray:
 
 
 def exec_time_dict(traffic: Mapping[str, float], dev: DeviceConfig) -> float:
-    """The legacy dict API, kept as a thin shim over the vectorized core.
+    """The string-keyed dict API, a thin face over the vectorized core.
 
     Honors an explicit ``internal_accesses`` key (fig12's miracle variant
     passes a reduced total that is NOT the category sum); otherwise derives
@@ -286,9 +250,9 @@ def pipeline_delivered_time(replay_deltas, migration_deltas, dev: DeviceLike,
     full-overlap bound: real channels would contend). ``False`` prices the
     synchronous path, ``replay_s + migration_s`` — migration on the
     critical path. ``overlapped <= sync`` holds segmentwise by
-    construction (max <= sum of non-negatives); benches assert it on the
-    same run's deltas. Note the per-segment max is NOT the cumulative
-    ``exec_time_vec`` of summed counters — the pipeline model resolves
+    construction (max <= sum of non-negatives); tests/test_fabric.py
+    asserts it on the same run's deltas. Note the per-segment max is NOT
+    the cumulative ``exec_time_vec`` of summed counters — the pipeline model resolves
     the bottleneck resource per segment, the cumulative model once."""
     xp = np if isinstance(replay_deltas, np.ndarray) else jnp
     t_replay = exec_time_vec(replay_deltas, dev, xp=xp)
@@ -325,9 +289,9 @@ def serve_modeled_time(counters: Mapping[str, int],
     Expanders move their own parked payloads in parallel (bottleneck =
     max over lanes); host syncs are serialized round trips charged at the
     slowest lane's CXL latency. Returns per-expander motion seconds plus
-    the totals the benches record (seconds per decode step is the figure
-    of merit: serial-vs-batched and fabric-striped serving compare in
-    seconds, not just tokens/sec)."""
+    the totals (seconds per decode step is the figure of merit:
+    serial-vs-batched and fabric-striped serving compare in seconds, not
+    just tokens/sec)."""
     lanes = stack_devices(list(devices), xp=np)
     motion = serve_motion_time(
         np.asarray(expander_stats["preempt_bytes"], np.float64),

@@ -11,11 +11,11 @@ A trace is (ospn[i], is_write[i], block[i]) plus a per-page rates table
 consumed by the payload-less pool (pool.rates_table).
 
 Every generator is a deterministic function of its explicit ``seed`` — the
-same seed the benches take on the CLI (``benchmarks/run.py --seed``) and
+same seed the launchers take on the CLI (``launch/figs.py --seed``) and
 the fabric derives its per-expander RNG streams from
 (``engine.state.make_pool_stack``: ``fold_in(seed, expander)``); fabric
 trace partitioning itself is a pure page-hash (fabric/placement.py). One
-flag therefore reproduces a whole ``BENCH_*.json`` run bit-for-bit.
+flag therefore reproduces a whole run bit-for-bit.
 """
 from __future__ import annotations
 

@@ -9,7 +9,7 @@ Two gradient-reduction modes:
     runs, the cross-device mean is done manually inside shard_map as
     psum_scatter(f32) + int8 all-gather with error feedback —
     ~1.8x fewer wire bytes than a ring all-reduce (the collective roofline
-    term; benchmarks/fig_gradcomp.py).
+    term).
 """
 from __future__ import annotations
 

@@ -1,5 +1,5 @@
-"""Shared test fixtures. NOTE: no XLA_FLAGS here — smoke tests and benches
-must see the single real CPU device; only launch/dryrun.py forces 512."""
+"""Shared test fixtures. NOTE: no XLA_FLAGS here — smoke tests must see
+the single real CPU device; only launch/dryrun.py forces 512."""
 import warnings
 
 import numpy as np

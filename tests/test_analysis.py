@@ -598,7 +598,7 @@ def test_verify_sync_counters():
 
 def test_hot_paths_declare_contracts():
     """The three load-bearing contracts are attached at runtime too (the
-    bench cross-checks resolve them via get_sync_contract)."""
+    runtime cross-checks resolve them via get_sync_contract)."""
     from repro.fabric.replay import Fabric
     from repro.serve.engine import Engine
 

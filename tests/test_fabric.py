@@ -125,20 +125,22 @@ def test_fabric_vs_merged_single_pool_within_tolerance():
     assert abs(tf - ts) / max(ts, 1) < 0.35, (tf, ts)
 
 
-def _saturating_fabric(n_pages=96, n_used=40):
+def _saturating_fabric(n_pages=96, n_used=40, **kw):
     """A fabric rigged to exhaust expander 0's compressed region: every
     page placed on expander 0 (WeightedInterleave [1, 0]); every page
     8-bit-compressible (4 single chunks, no aligned groups), so first-touch
     writes + watermark demotions demand ~160 chunks against 80 singles —
     the spill path must carry the overflow to the idle expander 1. (prom
     must be >= the clock engine's 16-entry fetch group; spill cadence is
-    one window so within-segment demand never outruns the watermark.)"""
+    one window so within-segment demand never outruns the watermark.)
+    ``kw`` passes on to ``Fabric`` (e.g. a mixed ``devices`` fleet)."""
     cfg = _small_cfg(prom=16, n_pages=n_pages, n_cchunks=96)
     rates = np.full((n_pages, cfg.blocks_per_page), 2, np.int32)
     placement = WeightedInterleave(2, n_pages, [1.0, 0.0])
     fab = Fabric(cfg, POLICY, placement, seed=0,
                  rates_table=jnp.asarray(rates), window=WINDOW,
-                 spill=True, spill_interval=WINDOW, spill_k=8, spill_low=40)
+                 spill=True, spill_interval=WINDOW, spill_k=8, spill_low=40,
+                 **kw)
     # one first-touch write per used page (single lap: the donor sees no
     # host access unless overrides redirect a later lap)
     ospn = np.arange(n_used, dtype=np.int32)
@@ -198,7 +200,6 @@ def test_delivered_time_mixed_fleet_per_expander_devices():
     the host float64 recompute; the host float64 values are bitwise the
     legacy scalar model per expander; and with identical traffic the gen4
     expander is strictly slower than the gen5 one."""
-    from repro.simx import device as DEV
     from repro.simx import time as TM
     from repro.simx.engine import TRAFFIC_KEYS
 
@@ -215,8 +216,8 @@ def test_delivered_time_mixed_fleet_per_expander_devices():
     assert np.allclose(per, in_jit, rtol=1e-4), (per, in_jit)
     for e, c in enumerate(fab.counters_by_expander()):
         internal = sum(c[k] for k in TRAFFIC_KEYS)
-        legacy = DEV.exec_time(dict(c, internal_accesses=internal),
-                               devices[e])
+        legacy = TM.exec_time_dict(dict(c, internal_accesses=internal),
+                                   devices[e])
         assert per[e] == legacy, f"expander {e} drifted from scalar model"
     assert fab.bottleneck_time() == per.max()
     # same counters on the slower generation must cost at least as much
@@ -246,6 +247,29 @@ def test_delivered_time_charges_spill_on_the_expander_where_it_occurs():
     dev = fab.devices[1]
     internal1 = sum(c1[k] for k in S.TRAFFIC_NAMES)
     assert per[1] == internal1 * 64 / (dev.channels * dev.ch_bw)
+
+
+def test_mixed_fleet_spill_priced_by_each_expanders_device():
+    """A skew-saturated mixed-generation fleet spills, and the migration
+    traffic is priced where it happens by that expander's own generation:
+    demotion-reads on the starved gen5 source, demotion-writes on the gen4
+    donor, each expander's delivered time bitwise the scalar model under
+    its own DeviceConfig."""
+    from repro.simx import time as TM
+    devices = [TM.DeviceConfig(), TM.DEVICE_PROFILES["gen4"]]
+    cfg, placement, fab, (ospn, wr, blk) = _saturating_fabric(
+        devices=devices)
+    fab.replay(ospn, wr, blk)
+    assert fab.spill_stats()["events"] > 0, "spill never fired"
+    c0, c1 = fab.counters_by_expander()
+    assert c0["demo_rd"] > 0 and c1["demo_wr"] > 0
+    per = fab.delivered_time()
+    for e, c in enumerate((c0, c1)):
+        internal = sum(c[k] for k in S.TRAFFIC_NAMES)
+        assert per[e] == TM.exec_time_dict(
+            dict(c, internal_accesses=internal), devices[e])
+    # the donor's spill traffic costs more on gen4 than it would on gen5
+    assert per[1] > TM.exec_time_dict(c1, TM.DeviceConfig())
 
 
 def test_fabric_segment_delta_tracking():

@@ -57,6 +57,25 @@ def _saturating_pair(n_devices, **kw):
         trace
 
 
+def _skewed4_pair(n_devices):
+    """(sharded fabric, vmap reference), both replayed with migration off:
+    four expanders under a skewed placement on a real workload trace."""
+    cfg = _small_cfg()
+    rates, ospn, wr, blk = _trace(cfg, n_accesses=120, seed=1)
+
+    def mk(**kw):
+        return Fabric(cfg, POLICY,
+                      WeightedInterleave(4, cfg.n_pages,
+                                         [0.55, 0.15, 0.15, 0.15]),
+                      seed=0, rates_table=jnp.asarray(rates), window=WINDOW,
+                      spill=False, **kw)
+
+    fab, ref = mk(shard_devices=n_devices), mk()
+    fab.replay(ospn, wr, blk)
+    ref.replay(ospn, wr, blk)
+    return fab, ref
+
+
 # ---------------------------------------------------------------------------
 # shard_map replay bit-identity vs the vmap oracle
 # ---------------------------------------------------------------------------
@@ -67,21 +86,7 @@ def test_shard_replay_bit_identical_to_vmap(n_devices):
     """Migration off: the shard_map-ed replay is bit-identical per
     expander to the vmap driver on a real workload trace — every pool
     leaf, counters included."""
-    n_exp = 4
-    cfg = _small_cfg()
-    rates, ospn, wr, blk = _trace(cfg, n_accesses=120, seed=1)
-
-    def mk(**kw):
-        return Fabric(cfg, POLICY,
-                      WeightedInterleave(n_exp, cfg.n_pages,
-                                         [0.55, 0.15, 0.15, 0.15]),
-                      seed=0, rates_table=jnp.asarray(rates), window=WINDOW,
-                      spill=False, **kw)
-
-    fab = mk(shard_devices=n_devices)
-    ref = mk()
-    fab.replay(ospn, wr, blk)
-    ref.replay(ospn, wr, blk)
+    fab, ref = _skewed4_pair(n_devices)
     assert fab.state_identical(ref)
     assert fab.counters_by_expander() == ref.counters_by_expander()
     ss = fab.sync_stats()
@@ -91,6 +96,17 @@ def test_shard_replay_bit_identical_to_vmap(n_devices):
     assert len(fab.segment_deltas) == len(ref.segment_deltas)
     for a, b in zip(fab.segment_deltas, ref.segment_deltas):
         assert (a == b).all()
+
+
+@pytest.mark.parametrize("n_devices", [
+    1, pytest.param(2, marks=needs(2)), pytest.param(4, marks=needs(4))])
+def test_sharded_delivered_time_is_mesh_invariant(n_devices):
+    """The modeled time does not depend on the mesh: every expander's
+    delivered seconds on a D-device mesh equal the vmap reference's
+    exactly, so the modeled accesses/s is the same at every D."""
+    fab, ref = _skewed4_pair(n_devices)
+    assert (fab.delivered_time() == ref.delivered_time()).all()
+    assert fab.bottleneck_time() == ref.bottleneck_time()
 
 
 @pytest.mark.parametrize("n_devices", [

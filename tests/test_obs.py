@@ -12,7 +12,7 @@
     ``Fabric.pipeline_times()`` — the trace is the same accounting, drawn;
   * histogram merge is associative (fixed bounds, bucket-wise add), so
     partial aggregations compose in any order;
-  * ``manifest()`` stamps the run facts every BENCH_*.json shares.
+  * ``manifest()`` stamps the run facts every obs export shares.
 """
 import json
 

@@ -1,5 +1,5 @@
-"""Fused demote/promote kernel parity + batched demotion bit-identity +
-measurement-calibrated device model (DESIGN.md §14).
+"""Fused demote/promote kernel parity + batched demotion bit-identity
+(DESIGN.md §14).
 
 The fused Pallas kernels must be *bit-identical* to the jnp oracle in
 ``core/compressor.py`` — same reciprocal-multiply quantization, same byte
@@ -8,7 +8,6 @@ dispatch on ``compress_impl`` without changing any pool state. Off-TPU the
 kernels run in interpret mode (this is the CI kernel-parity smoke)."""
 import dataclasses
 import functools
-import json
 
 import jax
 import jax.numpy as jnp
@@ -23,7 +22,6 @@ from repro.core import metadata as md
 from repro.core.engine import ops as OPS
 from repro.kernels import ops as kops
 from repro.kernels import qpack as qp
-from repro.simx import time as TM
 
 KEY = jax.random.PRNGKey(0)
 POL = E.DEFAULT_POLICY
@@ -301,50 +299,3 @@ def test_batched_demote_kernel_impl_bit_identical():
                       mcache_ways=4, demote_watermark=4, store_payload=True)
     pool, ser, ker = _demote_pair(base, n_writes=24, bat_impl="kernel")
     _check_pair(pool, ser, ker, what="kernel-impl pools")
-
-
-# -- measurement-calibrated device model -------------------------------------
-
-def test_calibrated_device_from_bench_file(tmp_path):
-    p = tmp_path / "BENCH_kernels.json"
-    p.write_text(json.dumps({"calibration": {
-        "compress_gbps": 4.0, "decompress_gbps": 64.0,
-        "block_bytes": 1024}}))
-    cal = TM.calibrated_device(path=p)
-    base = TM.DeviceConfig()
-    # cycles = clock * block_bytes / measured B/s
-    assert cal.comp_cycles == round(base.clock * 1024 / 4e9)
-    assert cal.decomp_cycles == round(base.clock * 1024 / 64e9)
-    assert cal != base
-    # everything but the engine constants is untouched
-    assert dataclasses.replace(cal, comp_cycles=base.comp_cycles,
-                               decomp_cycles=base.decomp_cycles) == base
-
-
-def test_calibrated_device_fallback_paths(tmp_path):
-    base = TM.DeviceConfig()
-    assert TM.calibrated_device(path=tmp_path / "missing.json") == base
-    bad = tmp_path / "bad.json"
-    bad.write_text("{not json")
-    assert TM.calibrated_device(path=bad) == base
-    empty = tmp_path / "empty.json"
-    empty.write_text("{}")
-    assert TM.calibrated_device(path=empty) == base
-    # custom base is respected
-    slow = TM.DEVICE_PROFILES["slow_engine"]
-    assert TM.calibrated_device(path=tmp_path / "missing.json",
-                                base=slow) == slow
-
-
-def test_calibrated_device_committed_artifact():
-    """The committed BENCH_kernels.json must actually move the engine
-    constants away from the paper fallback (acceptance criterion)."""
-    if not TM._BENCH_KERNELS.exists():
-        pytest.skip("no committed BENCH_kernels.json")
-    cal = TM.calibrated_device()
-    base = TM.DeviceConfig()
-    assert (cal.comp_cycles, cal.decomp_cycles) != \
-        (base.comp_cycles, base.decomp_cycles)
-    data = json.loads(TM._BENCH_KERNELS.read_text())
-    assert data["calibration"]["compress_gbps"] > 0
-    assert data["fused_vs_unfused"]["fused_ge_unfused"] is True

@@ -1,6 +1,6 @@
 """Delivered-time accounting tests (DESIGN.md §12).
 
-  * parity — the dict shim and the vectorized counter-array model are
+  * parity — ``exec_time_dict`` and the vectorized counter-array model are
     BITWISE identical to the pre-refactor scalar model (the legacy formula
     is transcribed verbatim below as the reference), on homogeneous
     configs, including the host=0 edge and the uncompressed baseline;
@@ -24,7 +24,6 @@ import pytest
 
 from repro.common.types import replace
 from repro.core.engine import state as S
-from repro.simx import device as DEV
 from repro.simx import time as TM
 
 
@@ -70,7 +69,7 @@ DEVICES = [TM.DeviceConfig(), TM.DeviceConfig(block_scale=4.0),
 def test_dict_shim_bitwise_parity_with_legacy_scalar():
     for t in _traffic_samples():
         for dev in DEVICES:
-            assert DEV.exec_time(t, dev) == _legacy_exec_time(t, dev)
+            assert TM.exec_time_dict(t, dev) == _legacy_exec_time(t, dev)
 
 
 def test_vectorized_counters_bitwise_parity_with_legacy_scalar():
@@ -91,7 +90,7 @@ def test_uncompressed_time_matches_legacy_and_counter_layout():
             {"host_reads": n, "host_writes": 0, "internal_accesses": n,
              "zero_served": 0, "promotions": 0, "demotions_dirty": 0},
             TM.DeviceConfig())
-        assert DEV.uncompressed_time(n, TM.DeviceConfig()) == legacy
+        assert TM.uncompressed_time(n, TM.DeviceConfig()) == legacy
     vec = TM.uncompressed_counters(9)
     assert vec.shape == (S.NUM_COUNTERS,)
     assert vec[S.C_HOST_RD] == 9 and S.traffic_vector(vec).sum() == 9
